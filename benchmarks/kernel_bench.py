@@ -115,17 +115,17 @@ def fused_ivf_bench():
                         gather (slab dtype), the gathered tensor is written
                         (f32 after dequant) and re-read by the einsum:
                         B*M*d * (s + 4 + 4) bytes.
-      fused kernel:     candidate rows stream HBM -> VMEM once (slab
-                        dtype) and are scored from VMEM; the (B, M, d)
-                        tensor never exists in HBM: B*M*d * s bytes
-                        (+ O(B*M) id operands, counted).
+      fused kernel:     each candidate's aligned HBM row group (T rows,
+                        T * s = 32 bytes of sublane per row index) streams
+                        HBM -> VMEM once and is scored from VMEM; the
+                        (B, M, d) tensor never exists in HBM:
+                        B*M*d * 32 bytes (+ O(B*M) id operands, counted).
 
-    s = slab itemsize. The headline row is the int8 slab — the serving
-    configuration (§14.3: the int8 slab exists precisely because this
-    lookup is memory-bound) — where fused/jnp = 1/9; the f32 slab row
-    (4/12 = 1/3) is reported alongside. Masked-candidate DMA skip and
-    dedup only lower the fused side further; the analytic numbers ignore
-    both (worst case for the kernel).
+    s = slab itemsize. The headline row is the int8 slab, where
+    fused/jnp = 32/9; the f32 slab row (32/12) is reported alongside. The
+    TPU cannot DMA a single slab row, so the kernel overfetches T-fold.
+    Masked-candidate DMA skip and dedup only lower the fused side; the
+    analytic numbers ignore both (worst case for the kernel).
     """
     from repro.core.index import IVFIndex
 
@@ -150,7 +150,7 @@ def fused_ivf_bench():
         f = jax.jit(lambda q, kk: ivf.search(st, q, kk, valid))
         t = _time(f, queries, slab)
         jnp_bytes = b * m * d * (s_item + 4 + 4)
-        fused_bytes = b * m * d * s_item + id_bytes
+        fused_bytes = b * m * d * 32 + id_bytes     # T-row groups
         name = ("kernel/ivf_fused_default" if label == "int8"
                 else f"kernel/ivf_fused_{label}")
         rows.append({

@@ -544,7 +544,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import SemanticCache, CacheConfig, DistributedCache
 
 rows = []
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 B = 64
 for cap in @CAPS@:
     cfg = CacheConfig(dim=64, capacity=cap, value_len=16, ttl=None,
@@ -583,7 +584,8 @@ for cap in @CAPS@:
             "us_per_call": 1e6 * walls[tag],
             "derived": (f"batch={B} dim=64 shards="
                         f"{1 if tag == 'local' else 4} parity={parity}"
-                        f" ratio={walls['sharded'] / walls['local']:.2f}"),
+                        f" ratio={walls['sharded'] / walls['local']:.2f}"
+                        f" platform={jax.default_backend()}"),
         })
 print("ROWS-JSON " + json.dumps(rows))
 """
@@ -595,8 +597,8 @@ def shard_table(full: bool = False):
     ``shard/*`` rows: the fused step's us/call, local single-device vs the
     4-shard ``DistributedCache`` on the same capacity, plus the hit-mask
     parity of the two paths on identical traffic. Runs in a subprocess
-    with XLA_FLAGS forcing 8 CPU devices — same machinery as
-    ``tests/test_distributed.py``.
+    on 8 forced CPU devices (``JAX_PLATFORMS=cpu``) — same machinery as
+    ``tests/test_distributed.py``; the rows say ``platform=cpu``.
     """
     import json
     import os
@@ -606,6 +608,9 @@ def shard_table(full: bool = False):
     caps = [1 << 16] + ([1 << 20] if full else [])
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # forced host devices: the child must not contend for an accelerator
+    # the parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", _SHARD_CHILD.replace("@CAPS@", repr(caps))],
         capture_output=True, text=True, timeout=1800, env=env)

@@ -83,7 +83,7 @@ def _write_json(path: str, rows: list, errors: list, argv: list) -> None:
     print(f"# wrote {len(doc['rows'])} rows -> {path}", file=sys.stderr)
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced dataset sizes (CI-friendly)")
@@ -139,13 +139,18 @@ def main() -> None:
             rows, _ = fn()
             _emit(rows)
             all_rows.extend(rows)
-        except Exception as e:  # noqa: BLE001 — keep the harness going
+        except Exception as e:  # noqa: BLE001 — run the rest, then exit 1
             print(f"{name}/ERROR,0.0,{type(e).__name__}: {e}")
             errors.append({"group": name, "error": f"{type(e).__name__}: {e}"})
 
     if args.json:
         _write_json(args.json, all_rows, errors, sys.argv[1:])
+    if errors:
+        print(f"# {len(errors)} group(s) failed: "
+              f"{', '.join(e['group'] for e in errors)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -549,7 +549,8 @@ def _sharded_child(args) -> dict:
     import numpy as np
 
     smoke = args.smoke
-    mesh = jax.make_mesh((4,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("data",))
     corpus = args.corpus or (40 if smoke else 400)
     batch = args.batch or (16 if smoke else 64)
     n_req = args.requests or (160 if smoke else 1000)
@@ -592,6 +593,7 @@ def _sharded_child(args) -> dict:
     e_ref = mk(None)
     r_sh = e_sh.process(workload)
     r_ref = e_ref.process(workload)
+    out["platform"] = jax.default_backend()
     out["parity_decisions_match"] = all(
         a.cached == b.cached for a, b in zip(r_ref, r_sh))
     out["parity_answers_match"] = all(
@@ -644,14 +646,17 @@ def _sharded_child(args) -> dict:
 
 
 def bench_sharded(args) -> dict:
-    """Stage 8 parent half: re-exec this script with a forced multi-device
-    CPU topology (the parent's JAX is already pinned to one device) and
-    collect the child's JSON summary."""
+    """Stage 8 parent half: re-exec this script on a forced multi-device
+    CPU topology (the parent's JAX already holds its device) and collect
+    the child's JSON summary, whose ``platform`` says ``cpu``."""
     import os
     import subprocess
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # forced host devices: the child must not contend for an accelerator
+    # the parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, os.path.abspath(__file__), "--sharded-child"]
     if args.smoke:
         cmd.append("--smoke")

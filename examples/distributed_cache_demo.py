@@ -18,8 +18,9 @@ import numpy as np  # noqa: E402
 from repro.core import CacheConfig, DistributedCache, SemanticCache  # noqa: E402
 from repro.embedding import HashEmbedder  # noqa: E402
 from repro.data.tokenizer import HashTokenizer  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 print(f"mesh: {dict(mesh.shape)} over {len(jax.devices())} devices")
 
 cache = SemanticCache(CacheConfig(dim=384, capacity=1024, value_len=24,

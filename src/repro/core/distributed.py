@@ -63,7 +63,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map_nocheck
 from repro.core import store
 from repro.core.cache import SemanticCache, _LocalComm
 from repro.core.runtime import CacheRuntime
@@ -346,8 +345,9 @@ class DistributedCache:
         are simply absent from the dict, so one wrapper serves every
         combination without None-leaf spec gymnastics; replication checking
         is off (the seam maintains replication invariants manually)."""
-        return shard_map_nocheck(body, self.mesh, (operand_specs,),
-                                 out_specs)(operands)
+        return jax.shard_map(body, mesh=self.mesh,
+                             in_specs=(operand_specs,), out_specs=out_specs,
+                             check_vma=False)(operands)
 
     # -- sharded methods (same signatures as SemanticCache) ----------------
     def lookup(self, runtime: CacheRuntime, queries: Array,

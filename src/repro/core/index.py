@@ -179,8 +179,8 @@ def _absorb_serial(buckets: Array, bucket_valid: Array, assign: Array,
 class IVFIndex:
     """Inverted-file ANN. ``refit`` = k-means rebuild; ``search`` = probe
     GEMM + fused candidate gather/score (``backend='auto'|'jnp'|'pallas'``
-    pins the candidate stage for parity tests — 'auto' follows the ops
-    dispatch: Pallas on TPU, jnp elsewhere)."""
+    pins both stages for parity tests and on-chip references — 'auto'
+    follows the ops dispatch: Pallas on TPU, jnp elsewhere)."""
 
     ncentroids: int = 64
     nprobe: int = 8
@@ -333,7 +333,8 @@ class IVFIndex:
         b = q.shape[0]
         p = min(self.nprobe, self.ncentroids)
         always = jnp.ones((ivf.centroids.shape[0],), dtype=bool)
-        _, probe = ops.cosine_topk(q, ivf.centroids, always, k=p)  # (B, P)
+        _, probe = ops.cosine_topk(q, ivf.centroids, always, k=p,
+                                   backend=self.backend)  # (B, P)
         cand = ivf.buckets[probe].reshape(b, -1)          # (B, M)
         visible = ivf.bucket_valid[probe].reshape(b, -1)  # (B, M)
         safe = jnp.maximum(cand, 0)

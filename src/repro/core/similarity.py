@@ -49,8 +49,11 @@ def cosine_scores(queries: Array, keys: Array, valid: Array | None = None,
     if not assume_normalized:
         queries = l2_normalize(queries)
         keys = l2_normalize(keys)
+    # f32 scores on every backend: a TPU's default precision rounds the
+    # operands, which moves scores by ~1e-3 at the hit threshold
     scores = jnp.einsum(
-        "bd,nd->bn", queries, keys, preferred_element_type=jnp.float32
+        "bd,nd->bn", queries, keys, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32
     )
     if valid is not None:
         mask = valid if valid.ndim == 2 else valid[None, :]

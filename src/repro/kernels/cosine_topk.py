@@ -6,24 +6,27 @@ on the MXU and a running top-k merge held in VMEM across grid steps.
 
 Tiling:
   grid = (B/BB, N/BN); the N axis is the minor (sequential) axis, so the
-  output blocks (BB, k) stay resident in VMEM and accumulate the running
-  top-k while key blocks (BN, d) stream HBM -> VMEM.
+  output blocks stay resident in VMEM and carry the running top-k while
+  key blocks (BN, d) stream HBM -> VMEM.
 
-  BB=128, BN=512, d<=1536  ->  VMEM working set per step:
-    keys  512 x 1536 x 4B = 3.0 MiB
-    q     128 x 1536 x 4B = 0.75 MiB
-    scores 128 x 512 x 4B = 0.25 MiB            << 16 MiB VMEM/core
-  The GEMM contraction dim (d: 384/768/1536) and BN are multiples of 128,
-  keeping the MXU systolic array fully tiled.
+  BB=128, BN=512 compile for a v5e within the default 16 MiB of scoped
+  VMEM at d = 384, 768 and 1536 (``tests/test_tpu_compile.py`` compiles
+  every variant at d=384). Per step: the double-buffered key block
+  (2 x 512 x d x 4B: 1.5 MiB at d=384, 6 MiB at d=1536), the query block
+  (2 x 128 x d x 4B) and the (128, 128 + 512) f32/int32 candidate rows of
+  the selection. BN=1024 runs out of VMEM at d=1536. The contraction dim
+  d and BN are multiples of 128, keeping the MXU fully tiled.
 
-Top-k strategy: ``k`` is tiny (<=8). A k-step unrolled argmax-and-suppress
-over the (BB, BN) score block is pure VPU work and avoids any sort network;
-the per-block winners then merge with the resident (BB, k) running set via
-one more k-step selection over the concatenated (BB, 2k) candidates.
+Top-k strategy: ``k`` is tiny (<=8). The running top-k lives in lanes
+[0, k) of the resident (BB, 128) output rows. Each step concatenates them
+ahead of the (BB, BN) score block and runs one k-step max-and-suppress
+over the lot — pure VPU/XLU work, no sort network. Mosaic cannot gather
+along lanes, so the winner's column and id come from masked reductions
+and land in their output lane by a lane-mask select.
 
 Masking (all fused, all optional — DESIGN.md §14):
-  * ``valid`` — shared (N,) aliveness (validity ∧ TTL), shipped as an
-    (N, 1) f32 column riding with each key block: the kernel-level analogue
+  * ``valid`` — shared (N,) aliveness (validity ∧ TTL), shipped as a
+    (1, N) f32 row riding with each key block: the kernel-level analogue
     of Redis lazy expiry.
   * per-row *intervals* — (B,) ``starts``/``sizes`` operands, one visible
     contiguous slot range per query row. The (BB, BN) visibility mask is
@@ -33,13 +36,15 @@ Masking (all fused, all optional — DESIGN.md §14):
     regions are contiguous by construction (§13.2).
   * dense per-row mask — a blocked (BB, BN) int8 mask operand for masks
     that are *not* contiguous ranges (e.g. future embedding-LSH bucket
-    coalescing). Costs B*N bytes of HBM traffic; prefer intervals.
+    coalescing), widened to int32 in VMEM for the compare. Costs B*N bytes
+    of HBM traffic; prefer intervals.
 
 int8 slabs: keys stored as ``round(normalized * 127)`` (store.insert) score
-through the same kernel with a uniform static ``key_scale = 1/127`` folded
-into the in-VMEM dequant — entrypoints apply it automatically for int8 keys
-so raw-int8 GEMMs (scores inflated x127) cannot happen. Per-row-scale
-quantization (``quantize_keys``) uses the (N, 1) ``scales`` operand instead.
+through the same kernel with a uniform static ``key_scale = 1/127``
+applied to the block's scores — entrypoints apply it automatically for
+int8 keys so raw-int8 scores (inflated x127) cannot happen. Per-row-scale
+quantization (``quantize_keys``) uses the (1, N) ``scales`` operand
+instead.
 
 All-masked rows (empty tenant region, padded row) return exactly
 ``(-inf, -1)`` — the same contract as ``ref.cosine_topk_ref``.
@@ -54,21 +59,33 @@ from jax.experimental import pallas as pl
 
 Array = jax.Array
 NEG_INF = -3.0e38  # finite -inf stand-in (python float: not a traced const)
+LANES = 128        # lane width of the resident running top-k rows (k <= 128)
 
 
 def _iter_topk(scores: Array, ids: Array, k: int) -> tuple[Array, Array]:
-    """k-step argmax-and-suppress. scores (B, M) f32, ids (B, M) i32."""
+    """k-step max-and-suppress. scores (B, M) f32, ids (B, M) i32.
+
+    Returns (B, LANES) score/id rows holding the top-k in lanes [0, k)
+    (NEG_INF / -1 beyond). Ties go to the lowest column, as in
+    ``lax.top_k``. Mosaic has no lane gather, so the winner's column and
+    id are found by masked reductions and written by a lane-mask select.
+    """
     b, m = scores.shape
     cols = jax.lax.broadcasted_iota(jnp.int32, (b, m), 1)
-    out_s, out_i = [], []
-    for _ in range(k):
-        best = jnp.max(scores, axis=1)
-        arg = jnp.argmax(scores, axis=1).astype(jnp.int32)
-        sel = jnp.take_along_axis(ids, arg[:, None], axis=1)[:, 0]
-        out_s.append(best)
-        out_i.append(jnp.where(best > NEG_INF, sel, -1))
-        scores = jnp.where(cols == arg[:, None], NEG_INF, scores)
-    return jnp.stack(out_s, axis=1), jnp.stack(out_i, axis=1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (b, LANES), 1)
+    out_s = jnp.full((b, LANES), NEG_INF, jnp.float32)
+    out_i = jnp.full((b, LANES), -1, jnp.int32)
+    for t in range(k):
+        best = jnp.max(scores, axis=1, keepdims=True)                # (B, 1)
+        arg = jnp.min(jnp.where(scores == best, cols, m), axis=1,
+                      keepdims=True)
+        win = cols == arg
+        sel = jnp.max(jnp.where(win, ids, -1), axis=1, keepdims=True)
+        out_s = jnp.where(lanes == t, best, out_s)
+        out_i = jnp.where(lanes == t, jnp.where(best > NEG_INF, sel, -1),
+                          out_i)
+        scores = jnp.where(win, NEG_INF, scores)
+    return out_s, out_i
 
 
 def _cosine_topk_kernel(q_ref, k_ref, ts_ref, ti_ref, *,
@@ -82,25 +99,27 @@ def _cosine_topk_kernel(q_ref, k_ref, ts_ref, ti_ref, *,
         ts_ref[...] = jnp.full_like(ts_ref, NEG_INF)
         ti_ref[...] = jnp.full_like(ti_ref, -1)
 
-    q = q_ref[...]                      # (BB, d) f32
-    kb = k_ref[...]                     # (BN, d) f32|bf16|int8
-    if scale_ref is not None:
-        kb = kb.astype(jnp.float32) * scale_ref[...]  # (BN,1) per-row scale
-    elif key_scale is not None:
-        kb = kb.astype(jnp.float32) * key_scale       # uniform int8 dequant
-    # MXU GEMM; contraction over d.
+    # MXU GEMM, contraction over d, at fp32 contract precision: Mosaic's
+    # default rounds f32 operands, which moves scores by ~1e-3 — enough to
+    # flip decisions at the threshold against the f32 oracle. Dequant
+    # scales multiply the (BB, BN) scores rather than the (BN, d) key
+    # block: the same product with d/BB fewer multiplies.
     s = jax.lax.dot_general(
-        q, kb.astype(jnp.float32),
+        q_ref[...], k_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)           # (BB, BN)
+    if key_scale is not None:
+        s = s * key_scale                             # uniform int8 dequant
+    if scale_ref is not None:
+        s = s * scale_ref[...]                        # (1, BN) per-row scale
 
-    base = j * block_n
     bb, bn = s.shape
-    gids = base + jax.lax.broadcasted_iota(jnp.int32, (bb, bn), 1)
+    gids = j * block_n + jax.lax.broadcasted_iota(jnp.int32, (bb, bn), 1)
 
     if valid_ref is not None:
-        vmask = valid_ref[...]          # (BN, 1) f32 0/1, shared by the batch
-        s = jnp.where((vmask[:, 0] > 0.5)[None, :], s, NEG_INF)
+        # (1, BN) f32 0/1 aliveness row, shared by the batch
+        s = jnp.where(valid_ref[...] > 0.5, s, NEG_INF)
     if start_ref is not None:
         # per-row interval visibility, built from iota in VMEM: row b sees
         # slots [start[b], start[b] + size[b]) — O(B) operands, no (B, N)
@@ -110,17 +129,14 @@ def _cosine_topk_kernel(q_ref, k_ref, ts_ref, ti_ref, *,
         s = jnp.where((gids >= start) & (gids < start + size), s, NEG_INF)
     if mask_ref is not None:
         # dense per-row mask block (BB, BN) int8 — the general
-        # (non-contiguous) visibility path
-        s = jnp.where(mask_ref[...] > 0, s, NEG_INF)
+        # (non-contiguous) visibility path; the TPU compares 32-bit lanes
+        s = jnp.where(mask_ref[...].astype(jnp.int32) > 0, s, NEG_INF)
 
-    blk_s, blk_i = _iter_topk(s, gids, k)
-
-    run_s, run_i = ts_ref[...], ti_ref[...]
-    cand_s = jnp.concatenate([run_s, blk_s], axis=1)   # (BB, 2k)
-    cand_i = jnp.concatenate([run_i, blk_i], axis=1)
-    new_s, new_i = _iter_topk(cand_s, cand_i, k)
-    ts_ref[...] = new_s
-    ti_ref[...] = new_i
+    # the running top-k sits in lanes [0, k) of the resident output rows,
+    # ahead of the block, so score ties keep the lower (earlier) slot
+    cand_s = jnp.concatenate([ts_ref[...], s], axis=1)   # (BB, LANES + BN)
+    cand_i = jnp.concatenate([ti_ref[...], gids], axis=1)
+    ts_ref[...], ti_ref[...] = _iter_topk(cand_s, cand_i, k)
 
 
 def _pad_to(x: Array, n: int, axis: int, fill) -> Array:
@@ -144,6 +160,8 @@ def _launch(queries: Array, keys: Array, *, valid=None, scales=None,
     are discarded by the final slice."""
     b, d = queries.shape
     n = keys.shape[0]
+    if not 0 < k <= LANES:
+        raise ValueError(f"k={k} must be within [1, {LANES}]")
     bb = min(block_b, max(8, b))
     bn = min(block_n, n)
     b_pad = -(-b // bb) * bb
@@ -167,12 +185,12 @@ def _launch(queries: Array, keys: Array, *, valid=None, scales=None,
 
     if scales is not None:
         add("scale_ref",
-            _pad_to(scales.astype(jnp.float32)[:, None], n_pad, 0, 0.0),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)))
+            _pad_to(scales.astype(jnp.float32)[None, :], n_pad, 1, 0.0),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)))
     if valid is not None:
         add("valid_ref",
-            _pad_to(valid.astype(jnp.float32)[:, None], n_pad, 0, 0.0),
-            pl.BlockSpec((bn, 1), lambda i, j: (j, 0)))
+            _pad_to(valid.astype(jnp.float32)[None, :], n_pad, 1, 0.0),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)))
     if starts is not None:
         add("start_ref",
             _pad_to(starts.astype(jnp.int32)[:, None], b_pad, 0, 0),
@@ -198,17 +216,17 @@ def _launch(queries: Array, keys: Array, *, valid=None, scales=None,
         grid=(b_pad // bb, n_pad // bn),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bb, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, LANES), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, LANES), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b_pad, k), jnp.float32),
-            jax.ShapeDtypeStruct((b_pad, k), jnp.int32),
+            jax.ShapeDtypeStruct((b_pad, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b_pad, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
     ts = jnp.where(ts <= NEG_INF, -jnp.inf, ts)
-    return ts[:b], ti[:b]
+    return ts[:b, :k], ti[:b, :k]
 
 
 _STATIC = ("k", "block_b", "block_n", "interpret", "key_scale")
@@ -238,9 +256,8 @@ def quant_cosine_topk_pallas(queries: Array, keys_q: Array, scales: Array,
                              ) -> tuple[Array, Array]:
     """int8-slab variant: keys int8 + per-row f32 scale, dequant in VMEM.
 
-    Cuts slab HBM traffic 4x vs f32 keys (the lookup is memory-bound at
-    large N — see EXPERIMENTS.md §Perf); dequant happens after the DMA, on
-    the block in VMEM, so the MXU still sees f32 operands.
+    Cuts slab HBM traffic 4x vs f32 keys; keys widen to f32 after the DMA,
+    on the block in VMEM, and the per-row scales multiply the scores.
     """
     del key_scale  # per-row scales take precedence by construction
     return _launch(queries, keys_q, scales=scales, valid=valid,

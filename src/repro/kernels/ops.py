@@ -49,14 +49,23 @@ def _interpret_requested() -> bool:
     return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
 
 
-def cosine_topk(queries: Array, keys: Array, valid: Array, *, k: int = 4
-                ) -> tuple[Array, Array]:
+def _pick_pallas(backend: str) -> bool:
+    """``backend`` is ``'auto' | 'jnp' | 'pallas'``; explicit values pin a
+    path for parity tests and on-chip references."""
+    if backend not in ("auto", "jnp", "pallas"):
+        raise ValueError(f"unknown backend {backend!r}")
+    return backend == "pallas" or (
+        backend == "auto" and (_use_pallas() or _interpret_requested()))
+
+
+def cosine_topk(queries: Array, keys: Array, valid: Array, *, k: int = 4,
+                backend: str = "auto") -> tuple[Array, Array]:
     """Masked cosine top-k with automatic backend dispatch.
 
     ``valid`` is (N,) shared across the batch or (B, N) per-row; the (B, N)
     shape routes to the dense blocked-mask kernel on TPU (contiguous
     per-row regions should use ``cosine_topk_interval`` instead)."""
-    if _use_pallas() or _interpret_requested():
+    if _pick_pallas(backend):
         interpret = not _use_pallas()
         if valid.ndim == 2:
             return cosine_topk_masked_pallas(queries, keys, valid, k=k,
@@ -112,8 +121,7 @@ def ivf_topk(queries: Array, keys: Array, cand: Array, *, k: int = 4,
     ``'auto' | 'jnp' | 'pallas'`` — explicit values pin a path for parity
     tests and benchmarks.
     """
-    if backend == "pallas" or (
-            backend == "auto" and (_use_pallas() or _interpret_requested())):
+    if _pick_pallas(backend):
         return ivf_topk_pallas(queries, keys, cand, k=k,
                                interpret=not _use_pallas())
     return ref.ivf_topk_ref(queries, keys, cand, k)

@@ -1,7 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These define the numerical contract; kernel tests sweep shapes/dtypes and
-``assert_allclose`` against these functions (interpret mode on CPU).
+``assert_allclose`` against these functions (interpret mode on CPU). The
+scoring contractions run at ``Precision.HIGHEST``: on a TPU the default
+rounds f32 operands, and the contract is f32 cosine scores.
 """
 from __future__ import annotations
 
@@ -37,6 +39,7 @@ def cosine_topk_ref(queries: Array, keys: Array, valid: Array, k: int
     if keys.dtype == jnp.int8:
         keys = keys.astype(jnp.float32) / 127.0
     scores = jnp.einsum("bd,nd->bn", queries, keys.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
     mask = valid if valid.ndim == 2 else valid[None, :]
     scores = jnp.where(mask, scores, NEG_INF)
@@ -108,6 +111,7 @@ def ivf_topk_ref(queries: Array, keys: Array, cand: Array, k: int
     safe = jnp.maximum(cand, 0)
     gathered = keys[safe].astype(jnp.float32)            # (B, M, d) — in HBM
     scores = jnp.einsum("bd,bmd->bm", queries, gathered,
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
     scores = jnp.where(cand >= 0, scores, NEG_INF)
     vals, pos = jax.lax.top_k(scores, k)

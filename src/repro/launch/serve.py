@@ -31,11 +31,54 @@ from repro.core.policy import AdaptiveThreshold
 from repro.core.types import CacheConfig
 from repro.data.qa_dataset import build_corpus, build_test_queries
 from repro.data.tokenizer import HashTokenizer
-from repro.serving import (AsyncCacheServer, CachedEngine, ModelBackend,
-                           Request, SchedulerConfig, SimulatedLLMBackend,
+from repro.launch.compile_cache import enable_compile_cache
+from repro.serving import (AsyncCacheServer, CachedEngine, LoadResult,
+                           ModelBackend, Request, SchedulerConfig,
+                           ServingMetrics, SimulatedLLMBackend,
                            build_multi_tenant_workload, run_closed_loop,
                            run_open_loop)
 from repro.tenancy import TenantRegistry
+
+
+def judge_for(pairs):
+    """Ground-truth judge over the corpus: a hit is positive when the
+    matched entry answers the request's semantic key."""
+    by_id = {p.qa_id: p for p in pairs}
+
+    def judge(req, sid):
+        return sid >= 0 and sid in by_id and \
+            by_id[sid].semantic_key == req.semantic_key
+    return judge
+
+
+def compile_serve_path(engine: CachedEngine, tenant: str = "default") -> None:
+    """Pre-trace the serve path with one request, then zero the
+    bookkeeping: the one-off jit compile must not flood every reported
+    end-to-end percentile."""
+    engine.serve_batch([Request(query="serve-path compile warmup",
+                                tenant=tenant)])
+    engine.metrics = ServingMetrics()
+
+
+async def serve_async(engine: CachedEngine, requests, *, max_batch: int,
+                      max_wait_ms: float = 5.0, coalesce: bool = True,
+                      tenant_weights: dict | None = None,
+                      rate_qps: float | None = None, concurrency: int = 32,
+                      metrics_port: int | None = None) -> LoadResult:
+    """Drive ``requests`` through an ``AsyncCacheServer`` over ``engine``:
+    open-loop Poisson arrivals at ``rate_qps``, else ``concurrency``
+    closed-loop clients. Responses come back in request order."""
+    sched = SchedulerConfig(max_batch=max_batch, max_wait_ms=max_wait_ms,
+                            coalesce=coalesce, tenant_weights=tenant_weights)
+    async with AsyncCacheServer(engine, sched) as server:
+        if metrics_port is not None:
+            mport = await server.serve_metrics(port=metrics_port)
+            print(f"/metrics exposition on http://127.0.0.1:{mport}/metrics")
+        if rate_qps:
+            return await run_open_loop(server.submit_request, requests,
+                                       rate_qps)
+        return await run_closed_loop(server.submit_request, requests,
+                                     concurrency=concurrency)
 
 
 def main():
@@ -88,14 +131,11 @@ def main():
                     help="always retain traces slower than this many ms, "
                          "even when the rate sampler would drop them")
     args = ap.parse_args()
+    enable_compile_cache()
 
     pairs = build_corpus(args.corpus, seed=0)
     queries = build_test_queries(pairs, n_per_category=args.queries, seed=1)
-    by_id = {p.qa_id: p for p in pairs}
-
-    def judge(req, sid):
-        return sid >= 0 and sid in by_id and \
-            by_id[sid].semantic_key == req.semantic_key
+    judge = judge_for(pairs)
 
     if args.backend == "sim":
         backend = SimulatedLLMBackend(pairs)
@@ -153,37 +193,15 @@ def main():
         mode = (f"open-loop {args.rate_qps:.0f} qps" if args.rate_qps
                 else f"closed-loop x{args.concurrency}")
         print(f"serving {len(queries)} queries (async scheduler, {mode}) ...")
-        # pre-trace the fused serve path, then zero the bookkeeping: the
-        # one-off jit compile (~seconds) must not flood every reported
-        # end-to-end percentile
-        from repro.serving import ServingMetrics
-        engine.serve_batch([Request(
-            query="serve-path compile warmup",
-            tenant="default" if registry is None else registry.names[0])])
-        engine.metrics = ServingMetrics()
-
-        async def drive():
-            sched = SchedulerConfig(max_batch=args.batch,
-                                    max_wait_ms=args.max_wait_ms,
-                                    coalesce=args.coalesce,
-                                    tenant_weights=None if registry is None
-                                    else registry.weights())
-            async with AsyncCacheServer(engine, sched) as server:
-                if args.metrics_port is not None:
-                    mport = await server.serve_metrics(
-                        port=args.metrics_port)
-                    print(f"/metrics exposition on "
-                          f"http://127.0.0.1:{mport}/metrics")
-                if args.rate_qps:
-                    res = await run_open_loop(server.submit_request,
-                                              requests, args.rate_qps)
-                else:
-                    res = await run_closed_loop(server.submit_request,
-                                                requests,
-                                                concurrency=args.concurrency)
-            print(f"sustained {res.achieved_qps:.1f} qps "
-                  f"({res.wall_s:.2f}s wall)")
-        asyncio.run(drive())
+        compile_serve_path(engine, "default" if registry is None
+                           else registry.names[0])
+        res = asyncio.run(serve_async(
+            engine, requests, max_batch=args.batch,
+            max_wait_ms=args.max_wait_ms, coalesce=args.coalesce,
+            tenant_weights=None if registry is None else registry.weights(),
+            rate_qps=args.rate_qps, concurrency=args.concurrency,
+            metrics_port=args.metrics_port))
+        print(f"sustained {res.achieved_qps:.1f} qps ({res.wall_s:.2f}s wall)")
         if tracer is not None and tracer.retained:
             print("trace stage decomposition (retained traces):")
             print(json.dumps(tracer.stage_decomposition(), indent=1))

@@ -120,7 +120,6 @@ def moe_ffn_sharded(mesh, data_axes: tuple[str, ...], model_axes: tuple[str, ...
     ``model_axes``. Router weights replicate.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map_nocheck
 
     def fn(x, w_router, w_gate, w_up, w_down, topk, capacity_factor):
         y, aux = moe_ffn(x, w_router, w_gate, w_up, w_down, topk=topk,
@@ -135,8 +134,8 @@ def moe_ffn_sharded(mesh, data_axes: tuple[str, ...], model_axes: tuple[str, ...
 
     def wrapped(x, w_router, w_gate, w_up, w_down, *, topk, capacity_factor):
         f = lambda a, b, c, dd, ee: fn(a, b, c, dd, ee, topk, capacity_factor)
-        return shard_map_nocheck(
-            f, mesh=mesh,
+        return jax.shard_map(
+            f, mesh=mesh, check_vma=False,
             in_specs=(P(data_axes, None), P(), P(None, None, model_axes),
                       P(None, None, model_axes), P(None, model_axes, None)),
             out_specs=(P(data_axes, None), P()),

@@ -29,7 +29,8 @@ class TestDistributedCache:
         out = run_with_devices("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.core import SemanticCache, CacheConfig, DistributedCache
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             cfg = CacheConfig(dim=32, capacity=256, value_len=8, ttl=1e9)
             dc = DistributedCache(SemanticCache(cfg), mesh)
             rt = dc.init()
@@ -71,7 +72,8 @@ class TestDistributedCache:
         out = run_with_devices("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.core import SemanticCache, CacheConfig, DistributedCache
-            mesh = jax.make_mesh((4,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("data",))
             cfg = CacheConfig(dim=16, capacity=64, value_len=4, ttl=10.0)
             dc = DistributedCache(SemanticCache(cfg), mesh)
             rt = dc.init()
@@ -105,7 +107,8 @@ class TestShardedModel:
             import dataclasses
             cfg = dataclasses.replace(get_arch("yi-6b").reduced(),
                                       vocab_pad_multiple=64)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             model = Model(cfg, mesh=mesh)
             params = model.init_params(jax.random.PRNGKey(0))
             pspec = param_pspecs(cfg, ("data",))
@@ -139,7 +142,8 @@ class TestShardedModel:
         out = run_with_devices("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.models.moe import moe_ffn, moe_ffn_sharded
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             d, ff, e, t = 32, 64, 4, 16
             ks = jax.random.split(jax.random.PRNGKey(0), 5)
             x = jax.random.normal(ks[0], (t, d))
@@ -195,7 +199,8 @@ class TestDistributedEquivalence:
             lres, _ = local.lookup(lrt, queries, 1.0)
 
             # distributed: same inserts via the sharded step
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             dc = DistributedCache(SemanticCache(cfg), mesh)
             drt = dc.init()
             step = dc.make_lookup_insert()

@@ -362,3 +362,24 @@ class TestOpsDispatch:
                         backend="jnp")
         _check(jnp_.search(st, q, keys, valid),
                auto.search(st, q, keys, valid))
+
+    def test_jnp_backend_runs_no_kernel(self, monkeypatch):
+        """backend='jnp' pins the centroid probe as well as the candidate
+        stage: the on-chip reference must not touch a Pallas kernel."""
+        def refuse(*a, **kw):
+            raise AssertionError("a Pallas kernel ran under backend='jnp'")
+        monkeypatch.setattr(ops, "cosine_topk_pallas", refuse)
+        monkeypatch.setattr(ops, "ivf_topk_pallas", refuse)
+        d, n, b = 32, 200, 5
+        keys = _unit(jax.random.PRNGKey(0), (n, d))
+        valid = jnp.ones((n,), bool)
+        index = IVFIndex(ncentroids=4, nprobe=2, bucket_cap=64, topk=3,
+                         backend="jnp")
+        st = _fitted(index, keys, valid)
+        _, ids = index.search(st, _near_queries(keys, b), keys, valid)
+        assert np.all(np.asarray(ids)[:, 0] >= 0)
+
+    def test_unknown_backend_rejected(self):
+        q = _unit(jax.random.PRNGKey(0), (2, 32))
+        with pytest.raises(ValueError, match="unknown backend"):
+            ops.cosine_topk(q, q, jnp.ones((2,), bool), k=1, backend="gpu")

@@ -22,7 +22,8 @@ class TestVariantNumericalEquivalence:
             from repro.launch.sharding import param_pspecs
             cfg = dataclasses.replace(get_arch("yi-6b").reduced(),
                                       vocab_pad_multiple=64)
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("data", "model"))
             tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0,
                                         cfg.vocab)
             outs = {}

@@ -36,7 +36,8 @@ PARITY_HARNESS = """
     make = lambda: SemanticCache(cfg, policy=pol, partition=part,
                                  fusion=fus, index=index)
     local = make()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     dc = DistributedCache(make(), mesh)
     assert dc.num_shards == 4
     lrt, drt = local.init(), dc.init()
@@ -146,7 +147,8 @@ class TestShardedParity:
             from repro.core import SemanticCache, CacheConfig, \\
                 DistributedCache
             cfg = CacheConfig(dim=16, capacity=128, value_len=4, ttl=None)
-            mesh = jax.make_mesh((4,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("data",))
             dc = DistributedCache(SemanticCache(cfg), mesh)
             rt = dc.init()
             ins = jax.jit(lambda rt, q, v, vl, t, m:
@@ -196,7 +198,8 @@ class TestShardedParity:
             e1.save_cache(snap)
             stats1 = e1.tenant_stats()
 
-            mesh = jax.make_mesh((4,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("data",))
             e2 = mk(mesh)
             try:
                 e2.load_cache(snap, reshard=False)
