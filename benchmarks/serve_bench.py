@@ -432,7 +432,7 @@ def bench_observability(pairs, *, batch: int, n_req: int, rate_qps: float,
     # (c) tracing off = zero per-request tracing work
     off_eng = make_engine(pairs, batch_size=batch, warm=False)
     off_eng.process(sync_wl[:batch])
-    out["off_path_traces_started"] = off_eng.tracer.started
+    out["off_path_traces_finished"] = off_eng.tracer.finished
     return out
 
 
@@ -844,8 +844,8 @@ def main(argv=None) -> int:
         print("FAIL: /metrics scrape missing families or tenant labels",
               file=sys.stderr)
         ok = False
-    if obs["off_path_traces_started"] != 0:
-        print("FAIL: tracing-off engine still started traces",
+    if obs["off_path_traces_finished"] != 0:
+        print("FAIL: tracing-off engine still finished traces",
               file=sys.stderr)
         ok = False
     if not (obs["events_logged"] > 0 and obs["events_bounded"]):

@@ -12,8 +12,9 @@ Two complementary drains for the numbers the stack already keeps:
   * ``prometheus_text`` / ``MetricsExporter`` — a text exposition in the
     Prometheus 0.0.4 format (``# HELP`` / ``# TYPE`` + samples) derived
     from ``ServingMetrics`` (host-side, incl. per-tenant labels), the
-    device-side ``CacheStats``/``TenancyState`` counters, and the
-    tracer's per-stage latency decomposition. Served from ``GET
+    device-side ``CacheStats``/``TenancyState`` counters, the tracer's
+    per-stage latency decomposition and the process's compile count
+    (``repro_xla_compiles_total``, ``repro.obs.compiles``). Served from ``GET
     /metrics`` on the TCP front-end and from ``repro.launch.serve
     --metrics-port``; any Prometheus-compatible scraper can poll it.
 
@@ -28,6 +29,8 @@ import itertools
 import json
 import time
 from collections import deque
+
+from repro.obs.compiles import compile_log
 
 #: Metric families the exposition always emits (CI's scrape assertion and
 #: the serve-bench smoke validate against this list, so it is the contract).
@@ -143,7 +146,7 @@ def _quantile_rows(out: _Lines, family: str, samples: dict, *,
 
 def prometheus_text(metrics, *, cache_stats=None, tenant_stats=None,
                     tracer=None, capacity: int | None = None,
-                    breaker=None) -> str:
+                    breaker=None, xla_compiles: int | None = None) -> str:
     """Render one scrape of the serving stack.
 
     ``metrics`` is a ``ServingMetrics``; the rest are optional extra
@@ -152,7 +155,9 @@ def prometheus_text(metrics, *, cache_stats=None, tenant_stats=None,
     ``repro.obs.Tracer`` (adds the per-stage decomposition), ``capacity``
     the slab capacity gauge, ``breaker`` the engine's ``CircuitBreaker``
     (adds the live state gauge; the transition counters are emitted
-    unconditionally — zeros without one — per REQUIRED_FAMILIES).
+    unconditionally — zeros without one — per REQUIRED_FAMILIES),
+    ``xla_compiles`` the process's lowering count
+    (``repro.obs.compile_log``).
     """
     out = _Lines()
     s = metrics  # host-side ServingMetrics
@@ -341,6 +346,14 @@ def prometheus_text(metrics, *, cache_stats=None, tenant_stats=None,
                     out.sample("repro_trace_stage_seconds",
                                {"stage": stage, "quantile": q}, row[key])
 
+    # compile plane: programs lowered in this process; a count that moves
+    # while serving means a new shape compiled on the request path
+    if xla_compiles is not None:
+        out.family("repro_xla_compiles_total", "counter",
+                   "Programs JAX lowered in this process (in-memory jit "
+                   "cache misses).")
+        out.sample("repro_xla_compiles_total", None, xla_compiles)
+
     return out.text()
 
 
@@ -360,4 +373,5 @@ class MetricsExporter:
             else None,
             tracer=eng.tracer,
             capacity=eng.cache.config.capacity,
-            breaker=None if res is None else res.breaker)
+            breaker=None if res is None else res.breaker,
+            xla_compiles=compile_log().lowerings)

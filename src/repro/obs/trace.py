@@ -5,26 +5,50 @@ hit?"); they cannot answer *instance* questions ("why did THIS request
 miss?", "which stage owns the p99?"). This module adds the missing layer:
 a ``RequestTrace`` of timestamped spans threaded through
 
-    AsyncScheduler.submit -> _form_batch -> _serve
+    AsyncScheduler.submit -> _admit -> _serve
     CachedEngine.serve_batch / process
     llm_backend.generate
 
-with the canonical stage names
+with the canonical stage names (children indented under their stage)
 
     queue_wait      admission queue (arrival -> batch formation)
+      engine_busy   arrival -> the scheduler loop last freed (the wait
+                    for the batch ahead to be served)
+      batching      from there -> the flush (the max_wait/occupancy wait)
     coalesce_attach waiter attached to an in-flight duplicate leader
     batch_form      deficit-round-robin micro-batch assembly
+    dispatch        formed batch on the event loop -> first engine stage
+                    on the worker thread (executor handoff)
     embed           host-side query embedding
-    device_step     compiled peek lookup (ANN search + threshold decide)
+    device_step     peek dispatch plus the host's wait for its hit mask:
+                    HOST time, not device time
+      peek_sync     the host blocked in ``np.asarray(peek.hit)``
     near_synthesis  host-side band-row synthesis (§17.3)
     backend_call    LLM backend round-trip for the miss set
     insert          fused commit + masked insert (the second jit dispatch)
+      step_sync     the host blocked in ``block_until_ready``
     respond         detokenize + judge + metrics + response construction
+      fetch         ``np.asarray(result.*)`` copies to the host
+      policy        judge plus ``update_policy``/``update_band``
+    resolve         ``serve_batch`` done -> the batch's last future set
 
-Engine-side spans are *contiguous* by construction (each stage's end is
-the next stage's start), so a trace's span sum reconstructs the measured
-end-to-end latency — the property the serve-bench obs stage asserts
-(span-sum within 10% of e2e at p50/p95).
+A top-level span has ``parent=None``; a child names its enclosing stage.
+Top-level spans are *contiguous* by construction (each stage's end is
+the next stage's start), so a trace's top-level span sum reconstructs
+the measured end-to-end latency; a stage's self time is its duration
+minus its children's. Each trace also carries ``meta["ready_t"]``, the
+instant its answer was known (end of ``peek_sync`` for a hit, of
+``near_synthesis`` for a synthesized near-hit, of ``backend_call``
+otherwise), so the time an answer was held before its future was set is
+``resolve``'s end minus ``ready_t``.
+
+While the tracer collects, every engine stage and child is also a host
+event ``serve.<name>`` in the ``jax.profiler`` trace
+(``jax.profiler.TraceAnnotation``, opened and closed on the engine's
+worker thread), and the scheduler's ``batch_form`` and ``resolve`` are
+the same on the event-loop thread: a profile then shows the host stages
+on the profiler's own clock beside the device's program runs.
+``dispatch`` crosses threads and is a program span only.
 
 Sampling (§18.2) is a *retention* policy, decided when a trace finishes:
 
@@ -39,22 +63,30 @@ Sampling (§18.2) is a *retention* policy, decided when a trace finishes:
 
 When tracing is **off** (``TraceConfig.off()`` / ``tracer=None`` on the
 engine) every hook degenerates to a shared ``_NullTrace`` singleton and a
-``None`` stage clock: no per-request allocation, no timestamp calls on
-the serve path — the hot path is byte-identical in behaviour to the
-pre-observability engine.
+``None`` stage clock: no per-request allocation, no timestamp calls and
+no profiler annotation on the serve path — the hot path is
+byte-identical in behaviour to the pre-observability engine.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
 from collections import deque
 
-#: Canonical stage names, in pipeline order. Exported so benchmarks and
-#: the exposition render decompositions in a stable order.
-STAGES = ("queue_wait", "coalesce_attach", "batch_form", "embed",
-          "device_step", "near_synthesis", "backend_call", "insert",
-          "respond")
+from jax import profiler
+
+#: Canonical stage names, in pipeline order, each child right after its
+#: stage. Exported so benchmarks and the exposition render decompositions
+#: in a stable order.
+STAGES = ("queue_wait", "engine_busy", "batching", "coalesce_attach",
+          "batch_form", "dispatch", "embed", "device_step", "peek_sync",
+          "near_synthesis", "backend_call", "insert", "step_sync",
+          "respond", "fetch", "policy", "resolve")
+
+#: Shared do-nothing context for a span that is not recorded (tracing off).
+NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,20 +120,25 @@ class TraceConfig:
 @dataclasses.dataclass
 class Span:
     """One timestamped stage. ``t0``/``t1`` are perf_counter seconds on
-    this process's clock — only differences are meaningful."""
+    this process's clock — only differences are meaningful. ``parent``
+    names the enclosing stage of a child span; None for a stage."""
 
     name: str
     t0: float
     t1: float
+    parent: str | None = None
 
     @property
     def duration_s(self) -> float:
         return self.t1 - self.t0
 
     def to_dict(self) -> dict:
-        return {"name": self.name, "t0": round(self.t0, 9),
-                "t1": round(self.t1, 9),
-                "duration_s": round(self.duration_s, 9)}
+        d = {"name": self.name, "t0": round(self.t0, 9),
+             "t1": round(self.t1, 9),
+             "duration_s": round(self.duration_s, 9)}
+        if self.parent is not None:
+            d["parent"] = self.parent
+        return d
 
 
 class RequestTrace:
@@ -117,15 +154,17 @@ class RequestTrace:
                                            # owner at resolution time)
         self.why: dict | None = None       # decision attribution (§18.3)
 
-    def add(self, name: str, t0: float, t1: float) -> None:
-        self.spans.append(Span(name, t0, t1))
+    def add(self, name: str, t0: float, t1: float,
+            parent: str | None = None) -> None:
+        self.spans.append(Span(name, t0, t1, parent))
 
     def annotate(self, **fields) -> None:
         self.meta.update(fields)
 
     @property
     def span_sum_s(self) -> float:
-        return sum(s.duration_s for s in self.spans)
+        """Sum of the top-level spans (children lie inside their stage)."""
+        return sum(s.duration_s for s in self.spans if s.parent is None)
 
     def stage_seconds(self) -> dict:
         """name -> summed seconds (a stage may appear once per batch)."""
@@ -158,7 +197,7 @@ class _NullTrace:
     spans: list = []
     meta: dict = {}
 
-    def add(self, name, t0, t1):
+    def add(self, name, t0, t1, parent=None):
         pass
 
     def annotate(self, **fields):
@@ -171,22 +210,83 @@ class _NullTrace:
 NULL_TRACE = _NullTrace()
 
 
-class StageClock:
-    """Contiguous stage timing for one batch: ``tick(name)`` closes the
-    open stage at ``name`` and opens the next one at the same instant, so
-    the recorded spans tile the batch's wall time exactly (no gaps, no
-    overlaps — the span-sum invariant)."""
+def profiler_event(name: str):
+    """Host event ``serve.<name>`` in the ``jax.profiler`` trace, from now
+    until the returned object's ``__exit__``, on the calling thread (a
+    TraceMe: it records nothing while no profile is being taken)."""
+    return profiler.TraceAnnotation(f"serve.{name}")
 
-    __slots__ = ("spans", "_t")
 
-    def __init__(self):
-        self.spans: list[Span] = []
-        self._t = time.perf_counter()
+class _Child:
+    """Context timing one child of a ``StageClock``'s open stage."""
 
-    def tick(self, name: str) -> None:
+    __slots__ = ("clock", "name", "span", "event")
+
+    def __init__(self, clock: "StageClock", name: str):
+        self.clock = clock
+        self.name = name
+
+    def __enter__(self):
+        self.event = profiler_event(self.name)
         t = time.perf_counter()
-        self.spans.append(Span(name, self._t, t))
-        self._t = t
+        self.span = Span(self.name, t, t, self.clock.stage)
+        self.clock.spans.append(self.span)
+        return self.span
+
+    def __exit__(self, *exc):
+        self.span.t1 = time.perf_counter()
+        self.event.__exit__(*exc)
+
+
+class StageClock:
+    """Contiguous stage timing for one batch, on the thread serving it.
+
+    The clock opens stage ``first`` when made; ``enter(name)`` closes the
+    open stage and opens ``name`` at the same instant, and ``close()``
+    closes the last, so the recorded stages tile the batch's wall time
+    exactly (no gaps, no overlaps — the span-sum invariant).
+    ``child(name)`` is a context that times a piece of the open stage as
+    its child. ``spans`` lists each span from its opening, a stage before
+    its children. Every stage and child is mirrored as a ``serve.<name>``
+    profiler event (``profiler_event``)."""
+
+    __slots__ = ("spans", "_span", "_event")
+
+    def __init__(self, first: str):
+        self.spans: list[Span] = []
+        self._open(first, time.perf_counter())
+
+    @property
+    def stage(self) -> str | None:
+        """Name of the open stage; None once closed."""
+        return None if self._span is None else self._span.name
+
+    def _open(self, name: str, t: float) -> None:
+        self._event = profiler_event(name)
+        self._span = Span(name, t, t)
+        self.spans.append(self._span)
+
+    def _shut(self) -> float:
+        t = time.perf_counter()
+        self._span.t1 = t
+        self._event.__exit__(None, None, None)
+        return t
+
+    def enter(self, name: str) -> None:
+        self._open(name, self._shut())
+
+    def close(self) -> None:
+        self._shut()
+        self._span = self._event = None
+
+    def child(self, name: str) -> _Child:
+        return _Child(self, name)
+
+
+def child_span(clock: StageClock | None, name: str):
+    """``clock.child(name)``, or the shared no-op context when ``clock``
+    is None (tracing off: no timestamp, no allocation)."""
+    return NO_SPAN if clock is None else clock.child(name)
 
 
 class Tracer:
@@ -199,7 +299,6 @@ class Tracer:
         self._acc = 0.0                       # deterministic rate sampler
         self._ring: deque[RequestTrace] = deque(
             maxlen=self.config.max_traces)
-        self.started = 0
         self.finished = 0
         self.retained = 0
 
@@ -212,15 +311,15 @@ class Tracer:
         """New trace, or the shared null trace when collection is off."""
         if not self.config.collecting:
             return NULL_TRACE
-        self.started += 1
         t = RequestTrace(f"rt-{next(self._seq):08d}")
         if meta:
             t.meta.update(meta)
         return t
 
-    def stage_clock(self) -> StageClock | None:
-        """Per-batch stage clock; None (no timestamp calls) when off."""
-        return StageClock() if self.config.collecting else None
+    def stage_clock(self, first: str) -> StageClock | None:
+        """Per-batch stage clock, its stage ``first`` open; None (no
+        timestamp calls, no profiler events) when off."""
+        return StageClock(first) if self.config.collecting else None
 
     # -- retention ------------------------------------------------------ #
     def _keep(self, trace: RequestTrace) -> bool:

@@ -58,8 +58,9 @@ from repro.core.runtime import CacheRuntime
 from repro.core.types import CacheConfig
 from repro.data.tokenizer import HashTokenizer
 from repro.embedding.hash_embedder import HashEmbedder
+from repro.obs.compiles import compile_log
 from repro.obs.explain import build_why, effective_edges
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, child_span
 from repro.serving.metrics import ServingMetrics
 
 
@@ -279,36 +280,49 @@ class CachedEngine:
         # engine — an empty pytree, so the compiled signature is unchanged).
         # ``w``/``wl`` are the per-row session turn windows (None on a
         # fusion-less engine — same empty-pytree trick, §16.3).
-        self._lookup_jit = jax.jit(
-            lambda rt, q, t, tid, w, wl: self.cache.lookup(
-                rt, q, t, tenant_id=tid, window=w, window_len=wl),
-            donate_argnums=(0,))
-        self._peek_jit = jax.jit(
-            lambda rt, q, t, tid, w, wl: self.cache.lookup(
-                rt, q, t, update_counters=False, tenant_id=tid,
-                window=w, window_len=wl)[0])
-        self._insert_jit = jax.jit(
-            lambda rt, q, v, vl, t, sid, m, tid: self.cache.insert(
-                rt, q, v, vl, t, source_id=sid, mask=m, tenant_id=tid),
-            donate_argnums=(0,))
-        self._step_jit = jax.jit(
-            lambda rt, q, mv, mvl, t, sid, peek, valid, tid, w, wl:
-            self.cache.step(
-                rt, q, mv, mvl, t, source_id=sid, peeked=peek, valid=valid,
-                tenant_id=tid, window=w, window_len=wl),
-            donate_argnums=(0,))
+        # Each function's name names its device program (``jit_peek``,
+        # ``jit_step``, ...) in profiles and compile logs.
+        cache = self.cache
+        compile_log()          # count compiles from the first program on
+
+        def lookup(rt, q, t, tid, w, wl):
+            return cache.lookup(rt, q, t, tenant_id=tid, window=w,
+                                window_len=wl)
+
+        def peek(rt, q, t, tid, w, wl):
+            return cache.lookup(rt, q, t, update_counters=False,
+                                tenant_id=tid, window=w, window_len=wl)[0]
+
+        def insert(rt, q, v, vl, t, sid, m, tid):
+            return cache.insert(rt, q, v, vl, t, source_id=sid, mask=m,
+                                tenant_id=tid)
+
+        def step(rt, q, mv, mvl, t, sid, peeked, valid, tid, w, wl):
+            return cache.step(rt, q, mv, mvl, t, source_id=sid,
+                              peeked=peeked, valid=valid, tenant_id=tid,
+                              window=w, window_len=wl)
+
         # standalone fusion op for the separate (reference) path, which
         # must insert the same fused keys the fused step would
-        self._fuse_jit = jax.jit(
-            lambda rt, q, w, wl: self.cache._maybe_fuse(rt, q, w, wl))
+        def fuse(rt, q, w, wl):
+            return cache._maybe_fuse(rt, q, w, wl)
+
         # top-k neighbour payload gather for the near-hit path (§17.3):
         # pure read of the slab, never donated — the runtime is reused by
         # the fused step right after, exactly like the peek
-        self._gather_topk_jit = jax.jit(
-            lambda rt, res: self.cache.gather_topk(rt, res))
-        self._refit_jit = jax.jit(
-            lambda rt, t, k: self.cache.refit(rt, t, k),
-            donate_argnums=(0,))
+        def gather_topk(rt, res):
+            return cache.gather_topk(rt, res)
+
+        def refit(rt, t, k):
+            return cache.refit(rt, t, k)
+
+        self._lookup_jit = jax.jit(lookup, donate_argnums=(0,))
+        self._peek_jit = jax.jit(peek)
+        self._insert_jit = jax.jit(insert, donate_argnums=(0,))
+        self._step_jit = jax.jit(step, donate_argnums=(0,))
+        self._fuse_jit = jax.jit(fuse)
+        self._gather_topk_jit = jax.jit(gather_topk)
+        self._refit_jit = jax.jit(refit, donate_argnums=(0,))
 
     # -- runtime views (read-only conveniences) ------------------------- #
     @property
@@ -876,6 +890,11 @@ class CachedEngine:
         starts one per real row and finishes it with the batch wall time
         (the sync ``process()`` path). When tracing is off there is no
         stage clock and no per-request allocation (§18.2).
+
+        With a stage clock each row's trace gets the batch's stages and
+        their children (``peek_sync``, ``step_sync``, ``fetch``,
+        ``policy``) and ``meta["ready_t"]``, the instant the row's answer
+        was known.
         """
         n_valid = len(batch)
         if self.resilience is not None:
@@ -883,7 +902,7 @@ class CachedEngine:
             # before the first fault (callers replace engine.metrics, so
             # this cannot live in __init__)
             self.metrics.resilience_seen = True
-        clock = self.tracer.stage_clock()
+        clock = self.tracer.stage_clock("embed")
         own_traces = False
         if clock is not None and traces is None:
             traces = [self.tracer.start() for _ in range(n_valid)]
@@ -917,7 +936,7 @@ class CachedEngine:
         emb = jnp.asarray(self.embedder.embed_batch([r.query for r in batch]))
         win, wlen, has_ctx = self._session_windows(batch)
         if clock is not None:
-            clock.tick("embed")
+            clock.enter("device_step")
         now = jnp.float32(self._now)
         self._maybe_refit()
 
@@ -932,10 +951,11 @@ class CachedEngine:
             # 1. pure peek: learn the miss set without committing any state
             #    (the only slab search this batch — step commits it, §7)
             peek = self._peek_jit(self.runtime, emb, now, tid, win, wlen)
-            peek_hit = np.asarray(peek.hit)
+            with child_span(clock, "peek_sync"):
+                peek_hit = np.asarray(peek.hit)
             cache_time = time.perf_counter() - t0
             if clock is not None:
-                clock.tick("device_step")
+                clock.enter("near_synthesis")
             if want_why:
                 # attribution snapshot (§18.3) — BEFORE the fused step
                 # donates the runtime and the policy feedback moves the
@@ -946,7 +966,7 @@ class CachedEngine:
             syn_by_row, syn_time, syn_cost = \
                 self._synthesize_near(batch, n_valid, peek)
             if clock is not None:
-                clock.tick("near_synthesis")
+                clock.enter("backend_call")
             miss_idx = [i for i in range(n_valid)
                         if not peek_hit[i] and i not in syn_by_row]
             # 2. backend answers the misses (paper §2.5 step 2). Failure
@@ -967,7 +987,7 @@ class CachedEngine:
                         failed[i] = err
             degraded = self._serve_degraded(batch, failed, peek)
             if clock is not None:
-                clock.tick("backend_call")
+                clock.enter("insert")
             # synthesized rows ride the same masked insert (insert mask is
             # ~hit, which includes band rows): the near-hit answer is
             # admitted under the query's own key (§17.4), carrying the
@@ -996,10 +1016,12 @@ class CachedEngine:
                 self.runtime, emb, jnp.asarray(miss_values),
                 jnp.asarray(miss_lens), now, sid, peek, jnp.asarray(valid),
                 tid, win, wlen)
-            jax.block_until_ready(result.hit)  # count the commit in cache_time
+            with child_span(clock, "step_sync"):
+                # count the commit in cache_time
+                jax.block_until_ready(result.hit)
             cache_time += time.perf_counter() - t1
             if clock is not None:
-                clock.tick("insert")
+                clock.enter("respond")
             self._inserts_since_rebuild += \
                 len(miss_idx) - len(failed) + len(syn_by_row)
         else:
@@ -1010,16 +1032,17 @@ class CachedEngine:
                 self._fuse_jit(self.runtime, emb, win, wlen)
             result, self.runtime = self._lookup_jit(self.runtime, femb, now,
                                                     tid, None, None)
-            lookup_hit = np.asarray(result.hit)
+            with child_span(clock, "peek_sync"):
+                lookup_hit = np.asarray(result.hit)
             cache_time = time.perf_counter() - t0
             if clock is not None:
-                clock.tick("device_step")
+                clock.enter("near_synthesis")
             if want_why:
                 why_ps, why_topk = self._why_snapshot(result)
             syn_by_row, syn_time, syn_cost = \
                 self._synthesize_near(batch, n, result)
             if clock is not None:
-                clock.tick("near_synthesis")
+                clock.enter("backend_call")
             miss_idx = [i for i in range(n)
                         if not lookup_hit[i] and i not in syn_by_row]
             # per-row insert payload: backend answers for misses, admitted
@@ -1045,7 +1068,7 @@ class CachedEngine:
             # reference path
             degraded = self._serve_degraded(batch, failed, result)
             if clock is not None:
-                clock.tick("backend_call")
+                clock.enter("insert")
             if syn_by_row:
                 rows = sorted(syn_by_row)
                 stoks, slens = self.tokenizer.encode_batch(
@@ -1068,24 +1091,27 @@ class CachedEngine:
                     jnp.asarray([row_lens[i] for i in ins], dtype=jnp.int32),
                     now, sid, jnp.ones((len(ins),), dtype=bool), mtid)
                 self._inserts_since_rebuild += len(ins)
+            # this path has no sync in its insert: the lookup's hit mask
+            # was already on the host, and the subset insert is left to run
             if clock is not None:
-                clock.tick("insert")
+                clock.enter("respond")
 
         if self.sessions is not None:
             self._append_turns(batch, n_valid,
                                self._canonical_keys(result, emb, win, wlen),
                                skip=failed)
 
-        hit = np.asarray(result.hit)
-        scores = np.asarray(result.score)
-        matched_sid = np.asarray(result.source_id)
-        near_row = np.asarray(result.near)
+        with child_span(clock, "fetch"):
+            hit = np.asarray(result.hit)
+            scores = np.asarray(result.score)
+            matched_sid = np.asarray(result.source_id)
+            near_row = np.asarray(result.near)
+            vals = np.asarray(result.values)
         near_served = np.zeros((n,), dtype=bool)
         for i in syn_by_row:
             near_served[i] = True
 
         # hit path: detokenize cached responses (real rows only)
-        vals = np.asarray(result.values)
         for i in range(n_valid):
             if hit[i]:
                 answers[i] = self.tokenizer.decode(vals[i])
@@ -1095,36 +1121,39 @@ class CachedEngine:
         # Synthesized near-hits are judged against their *synthesis*
         # provenance — the dominant neighbour's source_id (§17.3)
         positives = np.zeros((n,), dtype=bool)
-        if self.judge is not None:
-            for i in range(n_valid):
-                if hit[i]:
-                    positives[i] = self.judge(batch[i], int(matched_sid[i]))
-                elif near_served[i]:
-                    positives[i] = self.judge(
-                        batch[i], int(syn_by_row[i].source_id))
-            # adaptive-threshold feedback (paper §2.10): judged precision
-            # nudges the threshold toward the target
-            self.runtime = self.cache.update_policy(
-                self.runtime,
-                was_positive=jnp.asarray(positives & hit),
-                was_hit=jnp.asarray(hit))
-            if self.synthesizer is not None:
-                # judged near-hit outcomes nudge the band's lower edge
-                # (§17.2) — the near analogue of the adaptive threshold
-                self.runtime = self.cache.update_band(
+        with child_span(clock, "policy"):
+            if self.judge is not None:
+                for i in range(n_valid):
+                    if hit[i]:
+                        positives[i] = self.judge(batch[i],
+                                                  int(matched_sid[i]))
+                    elif near_served[i]:
+                        positives[i] = self.judge(
+                            batch[i], int(syn_by_row[i].source_id))
+                # adaptive-threshold feedback (paper §2.10): judged precision
+                # nudges the threshold toward the target
+                self.runtime = self.cache.update_policy(
                     self.runtime,
-                    was_positive=jnp.asarray(positives),
-                    was_near=jnp.asarray(near_served))
-        if self.judge is not None and degraded:
-            # degraded answers are judged for OBSERVATION only (§20.4):
-            # their precision is a brownout quality signal, but they never
-            # feed the threshold/band adaptation — an outage must not move
-            # the edges the healthy path serves under
-            rm = self.metrics.resilience
-            for i, d in degraded.items():
-                rm.degraded_judged += 1
-                if self.judge(batch[i], int(d[2])):
-                    rm.degraded_positives += 1
+                    was_positive=jnp.asarray(positives & hit),
+                    was_hit=jnp.asarray(hit))
+                if self.synthesizer is not None:
+                    # judged near-hit outcomes nudge the band's lower edge
+                    # (§17.2) — the near analogue of the adaptive threshold
+                    self.runtime = self.cache.update_band(
+                        self.runtime,
+                        was_positive=jnp.asarray(positives),
+                        was_near=jnp.asarray(near_served))
+            if self.judge is not None and degraded:
+                # degraded answers are judged for OBSERVATION only
+                # (§20.4): their precision is a brownout quality signal,
+                # but they never feed the threshold/band adaptation — an
+                # outage must not move the edges the healthy path serves
+                # under
+                rm = self.metrics.resilience
+                for i, d in degraded.items():
+                    rm.degraded_judged += 1
+                    if self.judge(batch[i], int(d[2])):
+                        rm.degraded_positives += 1
 
         # metrics: baseline = every query pays the LLM call. Only the
         # n_valid real rows are recorded — pad rows must not move counters,
@@ -1202,20 +1231,29 @@ class CachedEngine:
 
         responses = [_mk_response(i) for i in range(n_valid)]
         if clock is not None:
-            clock.tick("respond")
+            clock.close()
             if traces is not None:
-                # engine spans tile serve_batch's wall time contiguously
+                # engine stages tile serve_batch's wall time contiguously
                 # (§18.1), so for the sync path span-sum == e2e by
-                # construction; the scheduler prepends its queue-side
-                # spans and finishes with the true arrival->resolve e2e
-                batch_wall = sum(s.duration_s for s in clock.spans)
+                # construction; the scheduler adds its queue-side and
+                # handoff spans and finishes with the true e2e
+                ends = {sp.name: sp.t1 for sp in clock.spans}
+                batch_wall = sum(sp.duration_s for sp in clock.spans
+                                 if sp.parent is None)
                 for i in range(min(n_valid, len(traces))):
                     tr = traces[i]
                     if not tr:
                         continue
                     tr.spans.extend(clock.spans)
-                    tr.annotate(row=i, batch_rows=n_valid,
-                                path=_path_of(i))
+                    path = _path_of(i)
+                    # when the row's answer was known: a hit's at the peek's
+                    # sync, a synthesized near-hit's after synthesis, every
+                    # other row's after the backend call
+                    ready = ends["peek_sync"] if path == "hit" else (
+                        ends["near_synthesis"] if path == "near"
+                        else ends["backend_call"])
+                    tr.annotate(row=i, batch_rows=n_valid, path=path,
+                                ready_t=ready)
                     if whys is not None and whys[i] is not None:
                         tr.why = whys[i]
                     if own_traces:

@@ -63,6 +63,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from repro.obs.trace import NO_SPAN, Span, profiler_event
 from repro.serving.engine import CachedEngine, Request, Response
 from repro.serving.llm_backend import BackendError
 from repro.serving.resilience import Overloaded
@@ -380,10 +381,10 @@ class AsyncScheduler:
     # -- scheduler loop --------------------------------------------------- #
     async def _run(self) -> None:
         while True:
-            entries = await self._admit()
-            if entries is None:
+            admitted = await self._admit()
+            if admitted is None:
                 return
-            await self._serve(entries)
+            await self._serve(*admitted)
 
     def _form_batch(self) -> list[_Entry]:
         """Deficit-round-robin batch formation over backlogged tenants
@@ -413,10 +414,15 @@ class AsyncScheduler:
                 self._deficit[tenant] = 0.0
         return out
 
-    async def _admit(self) -> list[_Entry] | None:
+    async def _admit(self) -> tuple[list[_Entry], float | None] | None:
         """Block until a flush condition holds, then form one micro-batch.
         The flush trigger watches the *globally* oldest arrival, so no
-        tenant's deadline is hostage to another tenant's traffic."""
+        tenant's deadline is hostage to another tenant's traffic.
+        Returns the batch and, when tracing, the instant it was formed."""
+        tracing = self.engine.tracer.collecting
+        # the loop is free from here on: a request that arrived earlier
+        # waited for the batch ahead of it (``engine_busy``)
+        t_free = time.perf_counter() if tracing else None
         async with self._cond:
             while True:
                 if self._qlen > 0:
@@ -427,20 +433,28 @@ class AsyncScheduler:
                             or self._force_flush or self._stopping):
                         self._force_flush = False
                         t_flush = time.perf_counter()
-                        entries = self._form_batch()
-                        if self.engine.tracer.collecting:
+                        t_formed = None
+                        with (profiler_event("batch_form") if tracing
+                              else NO_SPAN):
+                            entries = self._form_batch()
+                        if tracing:
                             # queue-side spans (§18.1): queue_wait is
-                            # arrival -> flush decision, batch_form the
-                            # DRR assembly; the engine's contiguous stage
-                            # clock picks up from the executor handoff
+                            # arrival -> flush decision, split into the
+                            # wait for the loop to free and the wait for
+                            # the flush; batch_form the DRR assembly
                             t_formed = time.perf_counter()
                             for e in entries:
+                                t_busy = max(e.arrival, t_free)
                                 e.trace.add("queue_wait", e.arrival,
                                             t_flush)
+                                e.trace.add("engine_busy", e.arrival,
+                                            t_busy, parent="queue_wait")
+                                e.trace.add("batching", t_busy, t_flush,
+                                            parent="queue_wait")
                                 e.trace.add("batch_form", t_flush,
                                             t_formed)
                         self._cond.notify_all()   # wake blocked submitters
-                        return entries
+                        return entries, t_formed
                     timeout = self.config.max_wait_ms / 1000.0 - age_ms / 1000.0
                 elif self._stopping:
                     return None
@@ -451,9 +465,15 @@ class AsyncScheduler:
                 except asyncio.TimeoutError:
                     pass
 
-    async def _serve(self, entries: list[_Entry]) -> None:
-        """One engine round for one admission batch, off the event loop."""
+    async def _serve(self, entries: list[_Entry],
+                     t_formed: float | None) -> None:
+        """One engine round for one admission batch, off the event loop.
+        With tracing, each leader's trace gets ``dispatch`` (formed batch
+        -> the engine's first stage) and ``resolve`` (the engine's last
+        stage -> the batch's last future set), and every trace of the
+        batch finishes with its e2e measured to that last instant."""
         loop = asyncio.get_running_loop()
+        tracing = t_formed is not None
         # deadline budgets (§20.3): the engine must see the budget that
         # REMAINS after queue wait, so retries can never push a request
         # past the SLO its caller stated at submit time. Requests without
@@ -467,6 +487,13 @@ class AsyncScheduler:
                 r = dataclasses.replace(
                     r, deadline_ms=max(r.deadline_ms - waited_ms, 0.0))
             batch.append(r)
+        if tracing:
+            # one span shared by the batch's traces; it ends where the
+            # engine's first stage starts on the worker thread
+            dispatch = Span("dispatch", t_formed, t_formed)
+            for e in entries:
+                e.trace.spans.append(dispatch)
+            n_pre = len(entries[0].trace.spans)
         try:
             responses = await loop.run_in_executor(
                 self._executor,
@@ -486,6 +513,14 @@ class AsyncScheduler:
             return
         self.batches_served += 1
         done = time.perf_counter()
+        t_served = None
+        if tracing:
+            stages = [sp for sp in entries[0].trace.spans[n_pre:]
+                      if sp.parent is None]
+            dispatch.t1 = stages[0].t0
+            t_served = stages[-1].t1
+        finishing = []     # (trace, arrival, a waiter's attach end)
+        event = profiler_event("resolve") if tracing else None
         async with self._cond:
             for e, r in zip(entries, responses):
                 tenant = self._tenant_of(e.request)
@@ -501,15 +536,14 @@ class AsyncScheduler:
                     if not e.future.done():
                         e.future.set_exception(exc)
                     if e.trace:
-                        self.engine.tracer.finish(e.trace,
-                                                  e2e_s=done - e.arrival)
+                        finishing.append((e.trace, e.arrival, None))
                     for fut, w_arrival, wtr, _w_req in self._pending.pop(
                             key, []):
                         self.engine.metrics.record_latency(
                             "error", done - w_arrival, tenant=tenant)
                         if wtr:
-                            self.engine.tracer.finish(
-                                wtr, e2e_s=done - w_arrival)
+                            finishing.append((wtr, w_arrival,
+                                              _attach_end(wtr, w_arrival)))
                         if not fut.done():
                             fut.set_exception(exc)
                     continue
@@ -524,9 +558,7 @@ class AsyncScheduler:
                     e.future.set_result(
                         dataclasses.replace(r, latency_s=done - e.arrival))
                 if e.trace:
-                    # true client-observed e2e: queue wait + service
-                    self.engine.tracer.finish(e.trace,
-                                              e2e_s=done - e.arrival)
+                    finishing.append((e.trace, e.arrival, None))
                 # waiters inherit the leader's answer/decision; they paid
                 # no lookup and no backend call (and shared the leader's
                 # tenant — the coalesce key guarantees it; similarity
@@ -547,15 +579,24 @@ class AsyncScheduler:
                         w_resp = dataclasses.replace(
                             w_resp, why=self._waiter_why(r, w_req, key))
                     if wtr:
-                        t_att = wtr.spans[-1].t1 if wtr.spans else w_arrival
-                        wtr.add("respond", t_att, done)
+                        finishing.append((wtr, w_arrival,
+                                          _attach_end(wtr, w_arrival)))
                         wtr.why = w_resp.why
                         w_resp = dataclasses.replace(
                             w_resp, trace_id=wtr.trace_id)
-                        self.engine.tracer.finish(
-                            wtr, e2e_s=done - w_arrival)
                     if not fut.done():
                         fut.set_result(w_resp)
+        if event is not None:
+            event.__exit__(None, None, None)
+            # true client-observed e2e, to the batch's last future set
+            t_done = time.perf_counter()
+            resolve = Span("resolve", t_served, t_done)   # the batch's own
+            for tr, arrival, t_att in finishing:
+                if t_att is None:
+                    tr.spans.append(resolve)
+                else:
+                    tr.add("respond", t_att, t_done)
+                self.engine.tracer.finish(tr, e2e_s=t_done - arrival)
 
     @staticmethod
     def _waiter_why(r: Response, w_req: Request, leader_key: str) -> dict:
@@ -572,3 +613,11 @@ class AsyncScheduler:
         why.update(decision="coalesced", coalesced_into=leader_key,
                    leader_decision=leader_decision)
         return why
+
+
+def _attach_end(trace, arrival: float) -> float:
+    """End of a coalesced waiter's last top-level span (its attach)."""
+    for sp in reversed(trace.spans):
+        if sp.parent is None:
+            return sp.t1
+    return arrival

@@ -4,9 +4,14 @@ the metrics export plane (event ring, Prometheus exposition, /metrics
 endpoint), the bounded latency reservoirs, and the additive wire
 discipline on the TCP front-end."""
 import asyncio
+import glob
 import json
+import os
 import time
+import warnings
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -15,7 +20,7 @@ from repro.data.qa_dataset import build_corpus
 from repro.generative import BandPolicy, TemplateSplice
 from repro.obs import (NULL_TRACE, REQUIRED_FAMILIES, STAGES, EventLog,
                        MetricsExporter, RequestTrace, StageClock,
-                       TraceConfig, Tracer, effective_edges,
+                       TraceConfig, Tracer, compile_log, effective_edges,
                        prometheus_text)
 from repro.serving import (AsyncCacheServer, CachedEngine, Request,
                            SchedulerConfig, SimulatedLLMBackend)
@@ -61,7 +66,7 @@ class TestTracerRetention:
     def test_head_always_retained(self):
         tr = Tracer(TraceConfig(sample_rate=0.0, head=3))
         kept = [finished_trace(tr) for _ in range(10)]
-        assert tr.started == tr.finished == 10
+        assert tr.finished == 10
         assert tr.retained == 3
         assert [t.trace_id for t in tr.traces()] == \
             [t.trace_id for t in kept[:3]]
@@ -103,9 +108,9 @@ class TestTracerRetention:
         t.add("embed", 0.0, 1.0)            # all hooks are no-ops
         t.annotate(row=3)
         assert t.spans == [] and t.meta == {}
-        assert tr.stage_clock() is None
+        assert tr.stage_clock("embed") is None
         tr.finish(t, e2e_s=1.0)
-        assert tr.started == tr.finished == tr.retained == 0
+        assert tr.finished == tr.retained == 0
 
     def test_drain_clears_ring(self):
         tr = collect_all()
@@ -141,10 +146,12 @@ class TestTracerRetention:
 
 class TestStageClockAndTrace:
     def test_clock_spans_are_contiguous(self):
-        clock = StageClock()
-        for name in ("embed", "device_step", "respond"):
+        clock = StageClock("embed")
+        for name in ("device_step", "respond"):
             time.sleep(0.001)
-            clock.tick(name)
+            clock.enter(name)
+        time.sleep(0.001)
+        clock.close()
         spans = clock.spans
         assert [s.name for s in spans] == ["embed", "device_step", "respond"]
         for a, b in zip(spans, spans[1:]):
@@ -168,6 +175,29 @@ class TestStageClockAndTrace:
         assert [s["name"] for s in d["spans"]] == \
             ["embed", "embed", "respond"]
         json.dumps(d)                      # JSON-able for /traces
+
+    def test_child_spans_are_excluded_from_span_sum(self):
+        t = RequestTrace("rt-child")
+        t.add("device_step", 1.0, 1.5)
+        t.add("peek_sync", 1.2, 1.5, parent="device_step")
+        t.add("insert", 1.5, 1.75)
+        t.add("step_sync", 1.6, 1.7, parent="insert")
+        assert t.span_sum_s == pytest.approx(0.75)
+        d = t.to_dict()
+        assert [s.get("parent") for s in d["spans"]] == \
+            [None, "device_step", None, "insert"]
+
+    def test_clock_children_nest_in_the_open_stage(self):
+        clock = StageClock("device_step")
+        with clock.child("peek_sync") as sp:
+            time.sleep(0.001)
+        clock.enter("insert")
+        clock.close()
+        names = [(s.name, s.parent) for s in clock.spans]
+        assert names == [("device_step", None),
+                         ("peek_sync", "device_step"), ("insert", None)]
+        step = clock.spans[0]
+        assert step.t0 <= sp.t0 < sp.t1 <= step.t1
 
 
 # --------------------------------------------------------------------- #
@@ -197,9 +227,154 @@ class TestEngineTracing:
         eng = make_engine(pairs)               # no tracer argument
         assert not eng.tracer.collecting
         eng.process([Request(query="off-path probe")])
-        assert eng.tracer.started == 0
         assert eng.tracer.finished == 0
         assert eng.tracer.traces() == []
+
+    def test_ready_t_marks_when_each_answer_was_known(self, pairs):
+        eng = make_engine(pairs, tracer=collect_all())
+        eng.warm(pairs[:20])
+        reqs = [Request(query=pairs[i].question,
+                        semantic_key=pairs[i].semantic_key,
+                        source_id=pairs[i].qa_id) for i in range(3)]
+        reqs += [Request(query=q) for q in (
+            "entirely unrelated question about submarines",
+            "best sourdough starter feeding schedule")]
+        eng.process(reqs)
+        paths = set()
+        for t in eng.tracer.traces():
+            end = {s.name: s.t1 for s in t.spans}
+            want = "peek_sync" if t.meta["path"] == "hit" else "backend_call"
+            assert t.meta["ready_t"] == end[want]
+            paths.add(t.meta["path"])
+            # the children the fused path records, inside their stages
+            par = {s.name: s.parent for s in t.spans if s.parent}
+            assert par == {"peek_sync": "device_step",
+                           "step_sync": "insert", "fetch": "respond",
+                           "policy": "respond"}
+        assert paths == {"hit", "miss"}
+
+    def test_reference_path_records_its_own_syncs(self, pairs):
+        eng = make_engine(pairs, tracer=collect_all(), use_fused_step=False)
+        eng.warm(pairs[:10])
+        eng.process([Request(query=pairs[0].question),
+                     Request(query="how tall is mount kilimanjaro")])
+        for t in eng.tracer.traces():
+            par = {s.name: s.parent for s in t.spans if s.parent}
+            assert par == {"peek_sync": "device_step", "fetch": "respond",
+                           "policy": "respond"}
+            assert t.span_sum_s == pytest.approx(t.e2e_s, rel=1e-9)
+
+    def test_tracing_off_makes_no_profiler_annotation(self, pairs,
+                                                      monkeypatch):
+        def refuse(*a, **kw):
+            raise AssertionError("TraceAnnotation made with tracing off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        eng = make_engine(pairs)
+        eng.warm(pairs[:10])
+        eng.process([Request(query=pairs[0].question),
+                     Request(query="why is the sky blue at noon")])
+
+        async def serve():
+            async with AsyncCacheServer(eng, SchedulerConfig(
+                    max_batch=8, max_wait_ms=1.0)) as server:
+                return await asyncio.gather(
+                    *(server.submit(pairs[i].question) for i in range(4)))
+
+        assert len(asyncio.run(serve())) == 4
+        # and the same serve path does make them once the tracer collects
+        eng.tracer = collect_all()
+        with pytest.raises(AssertionError, match="tracing off"):
+            eng.process([Request(query=pairs[0].question)])
+
+    def test_profile_holds_host_stages_on_its_clock(self, pairs, tmp_path):
+        eng = make_engine(pairs, tracer=collect_all())
+        eng.warm(pairs[:20])
+        batch = [Request(query=pairs[0].question),
+                 Request(query="do tides depend on the moon")]
+        eng.process(batch)                     # compile outside the profile
+        jax.profiler.start_trace(str(tmp_path))
+        eng.process(batch)
+        jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                         recursive=True)[0]
+        data = jax.profiler.ProfileData.from_file(path)
+        stages = ["serve.embed", "serve.device_step", "serve.near_synthesis",
+                  "serve.backend_call", "serve.insert", "serve.respond"]
+        for plane in data.planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    evs = [e for e in line.events
+                           if e.name.startswith("serve.")]
+                    if evs:
+                        break
+                else:
+                    continue
+                break
+        else:
+            pytest.fail("no serve.* events on any host plane")
+        top = [e for e in evs if e.name in stages]
+        assert [e.name for e in top] == stages
+        window = None
+        for plane in data.planes:
+            if plane.name == "Task Environment":
+                with warnings.catch_warnings():
+                    # jax's stats type lacks a __module__; harmless
+                    warnings.simplefilter("ignore", DeprecationWarning)
+                    stats = dict(plane.stats)
+                window = (int(stats["profile_stop_time"])
+                          - int(stats["profile_start_time"]))
+        assert window is not None
+        for a, b in zip(top, top[1:]):
+            assert a.start_ns + a.duration_ns <= b.start_ns + 1e5
+        assert 0 <= top[0].start_ns
+        assert top[-1].start_ns + top[-1].duration_ns <= window
+        names = {e.name for e in evs}
+        assert {"serve.peek_sync", "serve.step_sync", "serve.fetch",
+                "serve.policy"} <= names
+
+
+# --------------------------------------------------------------------- #
+# compile counter and device program names
+# --------------------------------------------------------------------- #
+class TestCompilesAndPrograms:
+    def test_compile_counter_new_shape_once_repeat_never(self):
+        log = compile_log()
+        assert compile_log() is log            # one listener per process
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        n0 = log.lowerings
+        t0 = time.perf_counter()
+        f(np.ones(7, np.float32))
+        assert log.lowerings == n0 + 1
+        f(np.ones(7, np.float32))
+        assert log.lowerings == n0 + 1
+        f(np.ones(9, np.float32))
+        assert log.lowerings == n0 + 2
+        spans = log.spans(t0, time.perf_counter())
+        assert [s.kind for s in spans].count("lowering") == 2
+        assert all(t0 <= s.t0 <= s.t1 for s in spans)
+
+    def test_engine_programs_are_named(self, pairs):
+        eng = make_engine(pairs)
+        b = eng.batcher.batch_size
+        emb = jnp.zeros((b, 384), jnp.float32)
+        now = jnp.float32(0.0)
+        text = eng._peek_jit.lower(eng.runtime, emb, now, None, None,
+                                   None).as_text()
+        assert "jit_peek" in text
+        peek = eng._peek_jit(eng.runtime, emb, now, None, None, None)
+        text = eng._step_jit.lower(
+            eng.runtime, emb, jnp.zeros((b, 48), jnp.int32),
+            jnp.zeros((b,), jnp.int32), now, jnp.zeros((b,), jnp.int32),
+            peek, jnp.ones((b,), bool), None, None, None).as_text()
+        assert "jit_step" in text and "lambda" not in text
+
+    def test_metrics_export_the_compile_count(self, pairs):
+        eng = make_engine(pairs)
+        text = MetricsExporter(eng).render()
+        row = [ln for ln in text.splitlines()
+               if ln.startswith("repro_xla_compiles_total ")]
+        assert int(row[0].split()[1]) == compile_log().lowerings
 
 
 # --------------------------------------------------------------------- #

@@ -400,7 +400,8 @@ class TestCoalescedPathSplit:
         # the leader's trace carries the queue-side spans AND the engine's
         # contiguous stage spans; its span sum reconstructs its e2e
         lnames = [s.name for s in lt.spans]
-        assert lnames[:2] == ["queue_wait", "batch_form"]
+        top = [s.name for s in lt.spans if s.parent is None]
+        assert top[:3] == ["queue_wait", "batch_form", "dispatch"]
         assert {"embed", "device_step", "respond"} <= set(lnames)
         assert lt.span_sum_s == pytest.approx(lt.e2e_s, rel=0.10)
         # a waiter's whole life is attach -> respond, annotated with its
@@ -415,6 +416,73 @@ class TestCoalescedPathSplit:
         w = next(r.why for r in responses if r.why["decision"] == "coalesced")
         assert w["leader_decision"] == "miss"
         assert w["coalesced_into"]
+
+
+    def test_queue_wait_children_tile_it(self, pairs):
+        from repro.obs import TraceConfig, Tracer
+        eng = make_engine(pairs, batch_size=8, latency_s=0.03, block=True,
+                          tracer=Tracer(TraceConfig(sample_rate=1.0,
+                                                    head=0)))
+
+        async def staggered():
+            sched = SchedulerConfig(max_batch=8, max_wait_ms=2.0)
+            async with AsyncCacheServer(eng, sched) as server:
+                tasks = []
+                for q in DISTINCT_QUERIES:
+                    tasks.append(asyncio.create_task(server.submit(q)))
+                    await asyncio.sleep(0.01)
+                return await asyncio.gather(*tasks)
+
+        asyncio.run(staggered())
+        busy = []
+        for t in eng.tracer.traces():
+            qw = [s for s in t.spans if s.name == "queue_wait"]
+            kids = [s for s in t.spans if s.parent == "queue_wait"]
+            assert len(qw) == 1
+            assert [s.name for s in kids] == ["engine_busy", "batching"]
+            eb, bt = kids
+            # exact tiling: arrival -> loop free -> flush
+            assert eb.t0 == qw[0].t0 and eb.t1 == bt.t0
+            assert bt.t1 == qw[0].t1 and eb.t1 >= eb.t0
+            busy.append(eb.duration_s)
+        # requests that arrived during a 30 ms backend call waited for it
+        assert max(busy) > 0.005 and min(busy) == 0.0
+
+    def test_dispatch_and_resolve_close_the_trace(self, pairs):
+        from repro.obs import TraceConfig, Tracer
+        eng = make_engine(pairs, tracer=Tracer(
+            TraceConfig(sample_rate=1.0, head=0)))
+        eng.warm(pairs[:20])
+
+        async def serve():
+            sched = SchedulerConfig(max_batch=8, max_wait_ms=2.0)
+            async with AsyncCacheServer(eng, sched) as server:
+                return await asyncio.gather(
+                    *(server.submit(q) for q in
+                      [pairs[i].question for i in range(4)]
+                      + DISTINCT_QUERIES[:3]))
+
+        asyncio.run(serve())
+        traces = eng.tracer.traces()
+        assert len(traces) == 7
+        for t in traces:
+            top = [s for s in t.spans if s.parent is None]
+            assert [s.name for s in top] == [
+                "queue_wait", "batch_form", "dispatch", "embed",
+                "device_step", "near_synthesis", "backend_call", "insert",
+                "respond", "resolve"]
+            for a, b in zip(top, top[1:]):
+                assert a.t1 == b.t0            # contiguous, in order
+            assert all(s.t1 >= s.t0 for s in top)
+            # e2e is measured to the batch's last future set: resolve's end
+            assert t.span_sum_s == pytest.approx(t.e2e_s, rel=1e-9)
+            assert t.meta["ready_t"] <= top[-1].t1
+        # one dispatch and one resolve per batch, shared by its rows
+        per_batch = {name: {(s.t0, s.t1) for t in traces for s in t.spans
+                            if s.name == name}
+                     for name in ("embed", "dispatch", "resolve")}
+        assert len(per_batch["dispatch"]) == len(per_batch["embed"]) \
+            == len(per_batch["resolve"])
 
 
 class TestTCPServer:
