@@ -178,6 +178,11 @@ def prometheus_text(metrics, *, cache_stats=None, tenant_stats=None,
                "Cache hits by request category.")
     for cat, m in sorted(s.per_category.items()):
         out.sample("repro_cache_hits_total", {"category": cat}, m.hits)
+    out.family("repro_hits_released_early_total", "counter",
+               "Hits answered at their batch's peek, before its backend "
+               "call.")
+    out.sample("repro_hits_released_early_total", None,
+               s.hits_released_early)
     out.family("repro_positive_hits_total", "counter",
                "Judge-confirmed hits by request category.")
     for cat, m in sorted(s.per_category.items()):

@@ -30,7 +30,9 @@ with the canonical stage names (children indented under their stage)
     respond         detokenize + judge + metrics + response construction
       fetch         ``np.asarray(result.*)`` copies to the host
       policy        judge plus ``update_policy``/``update_band``
-    resolve         ``serve_batch`` done -> the batch's last future set
+    resolve         ``serve_batch`` done -> the batch's last future set;
+                    for a hit released at the peek (``release="early"``),
+                    the release -> its group's last future set
 
 A top-level span has ``parent=None``; a child names its enclosing stage.
 Top-level spans are *contiguous* by construction (each stage's end is

@@ -865,9 +865,53 @@ class CachedEngine:
         why["dry_run"] = True
         return why
 
+    def _release_hits(self, batch, n_valid: int, peek, peek_hit, has_ctx,
+                      traces, own_traces: bool, clock, cache_time: float,
+                      on_hits_ready) -> dict[int, str]:
+        """Early release (DESIGN.md §12.1): hand the peek's hit rows to
+        ``on_hits_ready(rows, responses)`` before the batch's misses go to
+        the backend. Only a batch with a plain miss (a row neither hit nor
+        in the near band) releases anything; rows that ask for a ``why``
+        are held, since theirs is built from the finished batch. Returns
+        row -> decoded answer of the released rows, for the final
+        responses to reuse."""
+        hit = peek_hit[:n_valid]
+        if hit.all() or (hit | np.asarray(peek.near)[:n_valid]).all():
+            return {}       # no backend call: the batch ends one step on
+        rows = [i for i in range(n_valid) if peek_hit[i]
+                and not (self.explain_all or batch[i].explain)]
+        if not rows:
+            return {}
+        vals = np.asarray(peek.values)
+        scores = np.asarray(peek.score)
+        answers = {i: self.tokenizer.decode(vals[i]) for i in rows}
+        responses = [Response(
+            answer=answers[i], cached=True, score=float(scores[i]),
+            latency_s=cache_time / n_valid, context=has_ctx[i],
+            trace_id="" if traces is None else traces[i].trace_id)
+            for i in rows]
+        if clock is not None:
+            # the batch's stages so far: ``near_synthesis`` has just opened
+            stages = clock.spans[:-1]
+            ready = next(s.t1 for s in stages if s.name == "peek_sync")
+            for i in rows:
+                tr = traces[i]
+                if not tr:
+                    continue
+                tr.spans.extend(stages)
+                tr.annotate(row=i, batch_rows=n_valid, path="hit",
+                            ready_t=ready, release="early")
+                if own_traces:
+                    self.tracer.finish(tr, e2e_s=tr.span_sum_s)
+        self.metrics.hits_released_early += len(rows)
+        on_hits_ready(rows, responses)
+        return answers
+
     def serve_batch(self, batch: list[Request], *,
                     record_path_latency: bool = True,
-                    traces: list | None = None) -> list[Response]:
+                    traces: list | None = None,
+                    on_hits_ready: Callable | None = None
+                    ) -> list[Response]:
         """Serve ONE admission batch: peek -> backend -> fused step commit.
 
         This is the pure device-side serve path (DESIGN.md §12.1): it does
@@ -895,6 +939,20 @@ class CachedEngine:
         their children (``peek_sync``, ``step_sync``, ``fetch``,
         ``policy``) and ``meta["ready_t"]``, the instant the row's answer
         was known.
+
+        ``on_hits_ready(rows, responses)``, when given, is called on the
+        fused path right after the peek's sync, before near-hit synthesis
+        and the backend, with the batch's hit rows and their responses
+        (built from the peek, which the step commits unchanged), so that a
+        caller can answer them without waiting for the batch's backend
+        call. It is called only when some row goes to the backend (a miss
+        outside the near band); a batch of hits ends one step later and is
+        returned whole. Rows that ask for a ``why`` are held to the end,
+        as are near-hits. The rest of the batch runs as without the
+        callback, and the returned list still holds every row. A released
+        row's trace keeps the stages up to the peek and is annotated
+        ``release="early"``; a caller that passed ``traces`` finishes it at
+        the release. Every other row's is annotated ``release="batch"``.
         """
         n_valid = len(batch)
         if self.resilience is not None:
@@ -943,6 +1001,7 @@ class CachedEngine:
         llm_time = 0.0
         llm_cost = 0.0
         answers: dict[int, str] = {}
+        released: dict[int, str] = {}     # early-released row -> answer
         want_why = self.explain_all or any(
             batch[i].explain for i in range(n_valid))
         why_ps = why_topk = None
@@ -956,6 +1015,10 @@ class CachedEngine:
             cache_time = time.perf_counter() - t0
             if clock is not None:
                 clock.enter("near_synthesis")
+            if on_hits_ready is not None:
+                released = self._release_hits(
+                    batch, n_valid, peek, peek_hit, has_ctx, traces,
+                    own_traces, clock, cache_time, on_hits_ready)
             if want_why:
                 # attribution snapshot (§18.3) — BEFORE the fused step
                 # donates the runtime and the policy feedback moves the
@@ -1111,10 +1174,12 @@ class CachedEngine:
         for i in syn_by_row:
             near_served[i] = True
 
-        # hit path: detokenize cached responses (real rows only)
+        # hit path: detokenize cached responses (real rows only); a
+        # released row's answer was decoded at its release
         for i in range(n_valid):
             if hit[i]:
-                answers[i] = self.tokenizer.decode(vals[i])
+                answers[i] = released[i] if i in released \
+                    else self.tokenizer.decode(vals[i])
 
         # judge hits (ground-truth oracle replaces GPT-4o-mini); pad rows
         # are never hits (valid-masked), so they contribute no feedback.
@@ -1242,7 +1307,7 @@ class CachedEngine:
                                  if sp.parent is None)
                 for i in range(min(n_valid, len(traces))):
                     tr = traces[i]
-                    if not tr:
+                    if not tr or i in released:
                         continue
                     tr.spans.extend(clock.spans)
                     path = _path_of(i)
@@ -1253,7 +1318,7 @@ class CachedEngine:
                         ends["near_synthesis"] if path == "near"
                         else ends["backend_call"])
                     tr.annotate(row=i, batch_rows=n_valid, path=path,
-                                ready_t=ready)
+                                ready_t=ready, release="batch")
                     if whys is not None and whys[i] is not None:
                         tr.why = whys[i]
                     if own_traces:

@@ -293,6 +293,8 @@ class ServingMetrics:
     queries: int = 0
     coalesced_calls: int = 0                # requests merged into in-flight
                                             # duplicates (scheduler, §12.3)
+    hits_released_early: int = 0            # hit rows answered at their
+                                            # batch's peek (§12.1)
     latency_samples: dict = dataclasses.field(
         default_factory=lambda: defaultdict(LatencyReservoir))
     # path -> LatencyReservoir (bounded, §18.5)

@@ -51,7 +51,9 @@ Invariants (tested in ``tests/test_scheduler.py`` / ``test_tenancy.py``):
     the engine's runtime is owned linearly), while the event loop stays
     free to accept and coalesce new arrivals;
   * every accepted request's future is resolved exactly once, also on
-    backend failure and on ``stop()`` (drain).
+    backend failure and on ``stop()`` (drain): a hit the engine releases
+    at its peek (§12.1) at that release, every other row at its batch's
+    end.
 """
 from __future__ import annotations
 
@@ -468,10 +470,15 @@ class AsyncScheduler:
     async def _serve(self, entries: list[_Entry],
                      t_formed: float | None) -> None:
         """One engine round for one admission batch, off the event loop.
-        With tracing, each leader's trace gets ``dispatch`` (formed batch
-        -> the engine's first stage) and ``resolve`` (the engine's last
-        stage -> the batch's last future set), and every trace of the
-        batch finishes with its e2e measured to that last instant."""
+
+        Each leader's future, and its waiters', is set once: a hit the
+        engine releases at its peek (``serve_batch``'s ``on_hits_ready``)
+        at that release, on the loop, while the batch's misses are still
+        at the backend; every other row when the batch ends. With tracing,
+        each leader's trace gets ``dispatch`` (formed batch -> the
+        engine's first stage) and ``resolve`` (the engine's last stage, or
+        the release, -> its group's last future set), and finishes with
+        its e2e measured to that last instant."""
         loop = asyncio.get_running_loop()
         tracing = t_formed is not None
         # deadline budgets (§20.3): the engine must see the budget that
@@ -494,15 +501,32 @@ class AsyncScheduler:
             for e in entries:
                 e.trace.spans.append(dispatch)
             n_pre = len(entries[0].trace.spans)
+        released: set[int] = set()    # rows resolved at the release
+
+        def release(rows: list[int], responses: list[Response]) -> None:
+            # on the loop; runs before the batch's own completion, which
+            # the worker hands over after it
+            released.update(rows)
+            t_release = None
+            if tracing:     # the end of the peek's stage
+                e = entries[rows[0]]
+                t_release = _attach_end(e.trace, e.arrival)
+            self._resolve_rows([(entries[i], r) for i, r in
+                                zip(rows, responses)], t_release)
+
         try:
             responses = await loop.run_in_executor(
                 self._executor,
                 lambda: self.engine.serve_batch(
                     batch, record_path_latency=False,
-                    traces=[e.trace for e in entries]))
+                    traces=[e.trace for e in entries],
+                    on_hits_ready=lambda rows, resp:
+                        loop.call_soon_threadsafe(release, rows, resp)))
         except Exception as exc:                    # resolve, never strand
             async with self._cond:
-                for e in entries:
+                for i, e in enumerate(entries):
+                    if i in released:
+                        continue
                     key = coalesce_key(e.request)
                     self._unregister_leader(key)
                     for fut, *_ in self._pending.pop(key, []):
@@ -512,85 +536,92 @@ class AsyncScheduler:
                         e.future.set_exception(exc)
             return
         self.batches_served += 1
-        done = time.perf_counter()
         t_served = None
+        rest = [(e, r) for i, (e, r) in enumerate(zip(entries, responses))
+                if i not in released]
         if tracing:
-            stages = [sp for sp in entries[0].trace.spans[n_pre:]
+            stages = [sp for sp in rest[0][0].trace.spans[n_pre:]
                       if sp.parent is None]
             dispatch.t1 = stages[0].t0
             t_served = stages[-1].t1
-        finishing = []     # (trace, arrival, a waiter's attach end)
-        event = profiler_event("resolve") if tracing else None
         async with self._cond:
-            for e, r in zip(entries, responses):
-                tenant = self._tenant_of(e.request)
-                key = coalesce_key(e.request)
-                if r.error:
-                    # per-row failure domain (§20.2): only the rows whose
-                    # backend call actually failed reject — hit/near/
-                    # degraded rows of the same flush resolved normally
-                    exc = BackendError(r.error)
-                    self._unregister_leader(key)
-                    self.engine.metrics.record_latency(
-                        "error", done - e.arrival, tenant=tenant)
-                    if not e.future.done():
-                        e.future.set_exception(exc)
-                    if e.trace:
-                        finishing.append((e.trace, e.arrival, None))
-                    for fut, w_arrival, wtr, _w_req in self._pending.pop(
-                            key, []):
-                        self.engine.metrics.record_latency(
-                            "error", done - w_arrival, tenant=tenant)
-                        if wtr:
-                            finishing.append((wtr, w_arrival,
-                                              _attach_end(wtr, w_arrival)))
-                        if not fut.done():
-                            fut.set_exception(exc)
-                    continue
-                # end-to-end latency: queue wait + service (the sync path's
-                # samples are service-only; these are what a client sees)
-                path = "degraded" if r.degraded else (
-                    "hit" if r.cached else
-                    ("near" if r.near_hit else "miss"))
+            self._resolve_rows(rest, t_served)
+
+    def _resolve_rows(self, rows: list[tuple[_Entry, Response]],
+                      t_resolve: float | None) -> None:
+        """Set the futures of ``rows`` (leader entry, its response) and of
+        their waiters, record their latencies, and drop the leaders'
+        pending state. With tracing (``t_resolve`` set: the instant their
+        answers were handed over), the leaders' traces share one
+        ``resolve`` span to the last future set, a waiter's gets its
+        ``respond``, and each finishes there."""
+        done = time.perf_counter()
+        finishing = []     # (trace, arrival, a waiter's attach end)
+        event = profiler_event("resolve") if t_resolve is not None else None
+        for e, r in rows:
+            tenant = self._tenant_of(e.request)
+            key = coalesce_key(e.request)
+            self._unregister_leader(key)
+            waiters = self._pending.pop(key, [])
+            if e.trace:
+                finishing.append((e.trace, e.arrival, None))
+            for _fut, w_arrival, wtr, _w_req in waiters:
+                if wtr:
+                    finishing.append((wtr, w_arrival,
+                                      _attach_end(wtr, w_arrival)))
+            if r.error:
+                # per-row failure domain (§20.2): only the rows whose
+                # backend call actually failed reject — hit/near/
+                # degraded rows of the same flush resolved normally
+                exc = BackendError(r.error)
                 self.engine.metrics.record_latency(
-                    path, done - e.arrival, tenant=tenant)
+                    "error", done - e.arrival, tenant=tenant)
                 if not e.future.done():
-                    e.future.set_result(
-                        dataclasses.replace(r, latency_s=done - e.arrival))
-                if e.trace:
-                    finishing.append((e.trace, e.arrival, None))
-                # waiters inherit the leader's answer/decision; they paid
-                # no lookup and no backend call (and shared the leader's
-                # tenant — the coalesce key guarantees it; similarity
-                # waiters additionally passed the cosine >= coalesce_sim
-                # verification against this leader)
-                self._unregister_leader(key)
-                for fut, w_arrival, wtr, w_req in self._pending.pop(
-                        key, []):
-                    # the waiter's latency files under its OWN "coalesced"
-                    # path — folding it into the leader's hit/miss bucket
-                    # would skew those paths' p99 (§18.5)
+                    e.future.set_exception(exc)
+                for fut, w_arrival, _wtr, _w_req in waiters:
                     self.engine.metrics.record_latency(
-                        "coalesced", done - w_arrival, tenant=tenant)
-                    w_resp = dataclasses.replace(
-                        r, coalesced=True, latency_s=done - w_arrival,
-                        trace_id="", why=None)
-                    if w_req.explain or self.engine.explain_all:
-                        w_resp = dataclasses.replace(
-                            w_resp, why=self._waiter_why(r, w_req, key))
-                    if wtr:
-                        finishing.append((wtr, w_arrival,
-                                          _attach_end(wtr, w_arrival)))
-                        wtr.why = w_resp.why
-                        w_resp = dataclasses.replace(
-                            w_resp, trace_id=wtr.trace_id)
+                        "error", done - w_arrival, tenant=tenant)
                     if not fut.done():
-                        fut.set_result(w_resp)
+                        fut.set_exception(exc)
+                continue
+            # end-to-end latency: queue wait + service (the sync path's
+            # samples are service-only; these are what a client sees)
+            path = "degraded" if r.degraded else (
+                "hit" if r.cached else
+                ("near" if r.near_hit else "miss"))
+            self.engine.metrics.record_latency(
+                path, done - e.arrival, tenant=tenant)
+            if not e.future.done():
+                e.future.set_result(
+                    dataclasses.replace(r, latency_s=done - e.arrival))
+            # waiters inherit the leader's answer/decision; they paid
+            # no lookup and no backend call (and shared the leader's
+            # tenant — the coalesce key guarantees it; similarity
+            # waiters additionally passed the cosine >= coalesce_sim
+            # verification against this leader)
+            for fut, w_arrival, wtr, w_req in waiters:
+                # the waiter's latency files under its OWN "coalesced"
+                # path — folding it into the leader's hit/miss bucket
+                # would skew those paths' p99 (§18.5)
+                self.engine.metrics.record_latency(
+                    "coalesced", done - w_arrival, tenant=tenant)
+                w_resp = dataclasses.replace(
+                    r, coalesced=True, latency_s=done - w_arrival,
+                    trace_id="", why=None)
+                if w_req.explain or self.engine.explain_all:
+                    w_resp = dataclasses.replace(
+                        w_resp, why=self._waiter_why(r, w_req, key))
+                if wtr:
+                    wtr.why = w_resp.why
+                    w_resp = dataclasses.replace(
+                        w_resp, trace_id=wtr.trace_id)
+                if not fut.done():
+                    fut.set_result(w_resp)
         if event is not None:
             event.__exit__(None, None, None)
-            # true client-observed e2e, to the batch's last future set
+            # true client-observed e2e, to the group's last future set
             t_done = time.perf_counter()
-            resolve = Span("resolve", t_served, t_done)   # the batch's own
+            resolve = Span("resolve", t_resolve, t_done)
             for tr, arrival, t_att in finishing:
                 if t_att is None:
                     tr.spans.append(resolve)
@@ -616,7 +647,8 @@ class AsyncScheduler:
 
 
 def _attach_end(trace, arrival: float) -> float:
-    """End of a coalesced waiter's last top-level span (its attach)."""
+    """End of a trace's last top-level span: a coalesced waiter's attach,
+    or the peek's stage on a leader's trace that the engine released."""
     for sp in reversed(trace.spans):
         if sp.parent is None:
             return sp.t1

@@ -2,6 +2,7 @@
 scheduler, in-flight coalescing, backpressure, async-vs-sync equivalence,
 partial-batch padding hygiene, and the extended serving metrics."""
 import asyncio
+import dataclasses
 import json
 import time
 
@@ -465,24 +466,36 @@ class TestCoalescedPathSplit:
         asyncio.run(serve())
         traces = eng.tracer.traces()
         assert len(traces) == 7
+        queue_side = ["queue_wait", "batch_form", "dispatch", "embed",
+                      "device_step"]
         for t in traces:
             top = [s for s in t.spans if s.parent is None]
-            assert [s.name for s in top] == [
-                "queue_wait", "batch_form", "dispatch", "embed",
-                "device_step", "near_synthesis", "backend_call", "insert",
-                "respond", "resolve"]
+            if t.meta["release"] == "early":
+                # a hit released at its peek: resolved while its batch's
+                # misses were at the backend
+                assert t.meta["path"] == "hit"
+                assert [s.name for s in top] == queue_side + ["resolve"]
+            else:
+                assert [s.name for s in top] == queue_side + [
+                    "near_synthesis", "backend_call", "insert", "respond",
+                    "resolve"]
             for a, b in zip(top, top[1:]):
                 assert a.t1 == b.t0            # contiguous, in order
             assert all(s.t1 >= s.t0 for s in top)
-            # e2e is measured to the batch's last future set: resolve's end
+            # e2e is measured to the row's last future set: resolve's end
             assert t.span_sum_s == pytest.approx(t.e2e_s, rel=1e-9)
             assert t.meta["ready_t"] <= top[-1].t1
-        # one dispatch and one resolve per batch, shared by its rows
+        # one dispatch per batch and one resolve per group resolved
+        # together (a batch that released its hits has two), shared by
+        # its rows
         per_batch = {name: {(s.t0, s.t1) for t in traces for s in t.spans
                             if s.name == name}
                      for name in ("embed", "dispatch", "resolve")}
+        releasing = {(s.t0, s.t1) for t in traces for s in t.spans
+                     if s.name == "embed" and t.meta["release"] == "early"}
+        assert releasing
         assert len(per_batch["dispatch"]) == len(per_batch["embed"]) \
-            == len(per_batch["resolve"])
+            == len(per_batch["resolve"]) - len(releasing)
 
 
 class TestTCPServer:
@@ -621,3 +634,229 @@ class TestShutdownUnderFire:
         assert all(isinstance(r, Response) for r in results)
         assert sum(r.coalesced for r in results) == 5
         assert sched._pending == {}
+
+
+class SlowBackend:
+    """A backend that sleeps ``delay_s`` in every call before answering
+    (or failing, with ``fail``), and records when each call returned."""
+
+    def __init__(self, inner, delay_s: float = 0.3, fail: bool = False):
+        self.inner = inner
+        self.delay_s = delay_s
+        self.fail = fail
+        self.returned: list[float] = []
+
+    def generate(self, queries, keys):
+        time.sleep(self.delay_s)
+        try:
+            if self.fail:
+                raise RuntimeError("backend down")
+            return self.inner.generate(queries, keys)
+        finally:
+            self.returned.append(time.perf_counter())
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def mixed_requests(pairs, n_hits=3, n_misses=2):
+    """Cached questions (hits) first, then distinct novel ones (misses)."""
+    return ([Request(query=pairs[i].question, category=pairs[i].category,
+                     source_id=pairs[i].qa_id,
+                     semantic_key=pairs[i].semantic_key)
+             for i in range(n_hits)]
+            + [Request(query=q) for q in DISTINCT_QUERIES[:n_misses]])
+
+
+def slow_engine(pairs, *, fail=False, **kw):
+    eng = make_engine(pairs, **kw)
+    eng.warm(pairs)
+    eng.serve_batch([Request(query="compile warmup")],
+                    record_path_latency=False)
+    eng.backend = SlowBackend(eng.backend, fail=fail)
+    return eng
+
+
+def response_fields(r: Response) -> dict:
+    d = dataclasses.asdict(r)
+    del d["latency_s"], d["trace_id"]
+    return d
+
+
+class TestEarlyHitRelease:
+    """A batch's hits are answered at its peek when its misses go to the
+    backend (DESIGN.md §12.1); every future is still set exactly once."""
+
+    def test_hits_and_waiters_done_before_the_backend_returns(self, pairs):
+        eng = slow_engine(pairs)
+        reqs = mixed_requests(pairs)
+        done_at: dict[int, float] = {}
+
+        async def drive():
+            sched = SchedulerConfig(max_batch=16, max_wait_ms=20.0)
+            server = AsyncCacheServer(eng, sched)
+            async with server:
+                async def timed(k, r):
+                    resp = await server.submit_request(r)
+                    done_at[k] = time.perf_counter()
+                    return resp
+
+                # each hit also has a duplicate that coalesces into it
+                subs = list(enumerate(reqs + reqs[:3]))
+                out = await asyncio.gather(*(timed(k, r) for k, r in subs))
+            return out, server.scheduler.batches_served
+
+        responses, batches = asyncio.run(drive())
+        assert batches == 1
+        (returned,) = eng.backend.returned
+        hit_rows = [0, 1, 2, 5, 6, 7]
+        for k in hit_rows:
+            assert responses[k].cached and done_at[k] < returned
+        assert sum(responses[k].coalesced for k in hit_rows) == 3
+        for k in (3, 4):
+            assert not responses[k].cached and done_at[k] >= returned
+        assert eng.metrics.hits_released_early == 3
+        assert eng.metrics.latency_samples["hit"].count == 3
+        assert eng.metrics.latency_samples["coalesced"].count == 3
+        assert eng.metrics.latency_samples["miss"].count == 2
+
+    def test_early_responses_equal_the_sync_path(self, pairs):
+        reqs = mixed_requests(pairs, n_hits=4, n_misses=3)
+        sync_eng = make_engine(pairs)
+        sync_eng.warm(pairs)
+        want = sync_eng.process(reqs)
+
+        eng = make_engine(pairs)
+        eng.warm(pairs)
+        got = []
+        full = eng.serve_batch(
+            reqs, on_hits_ready=lambda rows, rs: got.append((rows, rs)))
+        (rows, early), = got
+        assert rows == [0, 1, 2, 3]
+        for i, r in zip(rows, early):
+            assert response_fields(r) == response_fields(want[i])
+        # the returned list still holds every row, as the sync path's
+        assert [response_fields(r) for r in full] == \
+            [response_fields(r) for r in want]
+
+    def test_slab_and_stats_match_the_path_without_release(self, pairs):
+        import jax
+        reqs = mixed_requests(pairs, n_hits=4, n_misses=3)
+        engines = []
+        for callback in (None, lambda rows, rs: None):
+            eng = make_engine(pairs)
+            eng.warm(pairs)
+            eng.serve_batch(reqs, on_hits_ready=callback)
+            eng.serve_batch(reqs)       # the misses now hit
+            engines.append(eng)
+        plain, released = engines
+        assert released.metrics.hits_released_early == 4
+        for a, b in zip(jax.tree.leaves(plain.runtime),
+                        jax.tree.leaves(released.runtime)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # every count and rate; the wall times differ run to run
+        timed = ("latency_percentiles", "avg_latency_with_cache_s")
+        a, b = plain.metrics.summary(), released.metrics.summary()
+        assert {k: v for k, v in a.items() if k not in timed} == \
+            {k: v for k, v in b.items() if k not in timed}
+
+    def test_all_hit_batch_releases_nothing(self, pairs):
+        eng = slow_engine(pairs)
+        reqs = mixed_requests(pairs, n_hits=4, n_misses=0)
+        calls = []
+        eng.serve_batch(reqs, on_hits_ready=lambda *a: calls.append(a))
+
+        async def drive():
+            sched = SchedulerConfig(max_batch=16, max_wait_ms=20.0)
+            async with AsyncCacheServer(eng, sched) as server:
+                return await asyncio.gather(
+                    *(server.submit_request(r) for r in reqs))
+
+        assert all(r.cached for r in asyncio.run(drive()))
+        assert calls == [] and eng.backend.returned == []
+        assert eng.metrics.hits_released_early == 0
+
+    def test_backend_failure_after_release_rejects_only_misses(self, pairs):
+        eng = slow_engine(pairs, fail=True)
+        reqs = mixed_requests(pairs)
+        done_at: dict[int, float] = {}
+
+        async def drive():
+            sched = SchedulerConfig(max_batch=16, max_wait_ms=20.0)
+            server = AsyncCacheServer(eng, sched)
+            async with server:
+                async def timed(k, r):
+                    try:
+                        return await server.submit_request(r)
+                    finally:
+                        done_at[k] = time.perf_counter()
+
+                subs = list(enumerate(reqs + reqs))
+                out = await asyncio.gather(
+                    *(timed(k, r) for k, r in subs), return_exceptions=True)
+            return out, server.scheduler
+
+        results, sched = asyncio.run(drive())
+        (returned,) = eng.backend.returned
+        for k in (0, 1, 2, 5, 6, 7):
+            assert isinstance(results[k], Response) and results[k].cached
+            assert done_at[k] < returned
+        for k in (3, 4, 8, 9):
+            assert isinstance(results[k], BackendError)
+        assert sched._pending == {}
+        # one latency sample per future: none was resolved twice
+        s = eng.metrics.latency_samples
+        assert (s["hit"].count, s["coalesced"].count, s["error"].count) \
+            == (3, 3, 4)
+
+    def test_serve_batch_raising_after_release_fails_only_the_rest(
+            self, pairs):
+        eng = slow_engine(pairs)
+        reqs = mixed_requests(pairs)
+
+        def judge(req, sid):            # runs after the release
+            raise ValueError("judge down")
+
+        eng.judge = judge
+
+        async def drive():
+            sched = SchedulerConfig(max_batch=16, max_wait_ms=20.0)
+            server = AsyncCacheServer(eng, sched)
+            async with server:
+                out = await asyncio.gather(
+                    *(server.submit_request(r) for r in reqs + reqs),
+                    return_exceptions=True)
+            return out, server.scheduler
+
+        results, sched = asyncio.run(drive())
+        for k in (0, 1, 2, 5, 6, 7):
+            assert isinstance(results[k], Response) and results[k].cached
+        for k in (3, 4, 8, 9):
+            assert isinstance(results[k], ValueError)
+        assert sched._pending == {}
+
+    def test_key_of_a_released_hit_can_lead_again(self, pairs):
+        # once its hit is released, a leader's key is free: a duplicate
+        # arriving during the batch's backend call leads the next batch,
+        # and the end of the first batch leaves its waiters alone
+        eng = slow_engine(pairs)
+        reqs = mixed_requests(pairs, n_hits=1, n_misses=1)
+
+        async def drive():
+            sched = SchedulerConfig(max_batch=16, max_wait_ms=5.0)
+            async with AsyncCacheServer(eng, sched) as server:
+                first = [asyncio.create_task(server.submit_request(r))
+                         for r in reqs]
+                await asyncio.wait_for(asyncio.shield(first[0]), 5.0)
+                assert not first[1].done()      # its miss at the backend
+                again = [asyncio.create_task(server.submit_request(reqs[0]))
+                         for _ in range(3)]
+                return await asyncio.gather(*first, *again)
+
+        responses = asyncio.run(drive())
+        assert all(r.answer for r in responses)
+        assert [r.cached for r in responses] == [True, False, True, True,
+                                                 True]
+        assert [r.coalesced for r in responses[2:]] == [False, True, True]
+        assert eng.metrics.hits_released_early == 1
+

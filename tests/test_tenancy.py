@@ -427,7 +427,8 @@ class _FakeEngine:
         self.delay_s = delay_s          # default
         self.batches: list[list[str]] = []
 
-    def serve_batch(self, batch, record_path_latency=True, traces=None):
+    def serve_batch(self, batch, record_path_latency=True, traces=None,
+                    on_hits_ready=None):
         self.batches.append([r.tenant for r in batch])
         if self.delay_s:
             time.sleep(self.delay_s)
