@@ -4,13 +4,15 @@
 
 For each seed, in one process: set up the cell afresh, drive a window of
 ``--seconds`` at the cell's own load, and compare what the program served
-with the plain reference; then put the configuration's control in the
-program's place (the reference one precision step below the one the
-configuration states: bfloat16 x3 for float32 at HIGHEST) and compare
-that too. Prints one JSON line per seed with every compared number, for
-the program and for the control. The program's readings set each limit's
-lower end, the control's its upper end. ``--set`` replaces a top-level
-key of the configuration, to read the program at another setting.
+with the plain reference, by the check of the configuration's deployment;
+then put the configuration's control in the program's place (the
+reference one precision step below the one the configuration states, as
+the deployment defines it: bfloat16 x3 for float32 at HIGHEST in
+``llm_api``) and compare that too. Prints one JSON line per seed with
+every compared number, for the program and for the control. The
+program's readings set each limit's lower end, the control's its upper
+end. ``--set`` replaces a top-level key of the configuration, to read the
+program at another setting.
 """
 import time
 
@@ -25,20 +27,14 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 
 
-def control_of(spec):
-    """The scorer of the configuration's control."""
-    scorer = spec.config["control"]["scorer"]
-    return {"bf16x3": spec.reference().bf16x3_scores}[scorer]
-
-
 def readings(spec, seed: int, seconds: float, t_start: float) -> dict:
     from harness import runner
 
     run, corpus = runner.serve_window(spec, seed, seconds, traced=False,
                                       t_start=t_start)
-    ok, checks, seen = runner.reference_check(run, corpus, seed)
-    c_ok, c_checks, _ = runner.reference_check(run, corpus, seed,
-                                               control=control_of(spec))
+    check = spec.deployment().check
+    ok, checks, seen = check(run, corpus, seed)
+    c_ok, c_checks, _ = check(run, corpus, seed, control=True)
     return {"seed": seed, "program_correct": ok, "control_correct": c_ok,
             "program": {k: v["value"] for k, v in checks.items()},
             "control": {k: v["value"] for k, v in c_checks.items()},

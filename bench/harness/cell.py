@@ -10,7 +10,15 @@ file of its own, found by the name ``BENCHMARK.json`` gives it:
   ``read(run)`` returning a number or None. A metric split by what it
   moves, ``<stem>.<part>``, is read by ``<stem>.py`` where it has no file
   of its own;
-* ``bench/references/<reference>.py``: the configuration's plain reference.
+* ``bench/references/<reference>.py``: the configuration's plain reference;
+* ``bench/deployments/<deployment>.py``: how the configuration's program is
+  built and checked. It exports ``corpus_of(config)``;
+  ``build(config, corpus, seed, *, tracer=None) -> (engine, kept)``, a warm
+  engine and whatever its check needs that the answers do not carry
+  (the runner frees the engine before the check, so that the reference
+  never sets the memory peak); ``scheduler_config(config)``; and
+  ``check(run, corpus, seed, *, control=False) -> (ok, checks, seen)``,
+  which reads ``run.kept``.
 
 So a cell, a configuration or a metric is added with files and entries,
 without editing a file that is there.
@@ -63,6 +71,10 @@ class Spec:
     def reference(self):
         return load_module(BENCH / "references"
                            / f"{self.config['reference']}.py")
+
+    def deployment(self):
+        return load_module(BENCH / "deployments"
+                           / f"{self.config['deployment']}.py")
 
 
 def _by_name(entries: list, name: str, what: str) -> dict:

@@ -1,5 +1,5 @@
-"""Decide ``correct``: replay the program's batches through the plain
-reference and compare every answered request.
+"""The pieces a deployment's check is built from: replay the program's
+batches through a plain reference and compare every answered request.
 
 The scheduler serves one batch at a time; a batch's leaders are looked up
 against the slab as the batches before it left it, and its misses are
@@ -10,7 +10,8 @@ while one batch ends at least a slab scan after the last. The reference
 then serves the same leaders in the same batches; a coalesced waiter is
 owed its leader's answer.
 
-Numbers compared, each with its limit from the configuration:
+Numbers compared, each with its limit from the configuration (the
+deployment counts the last two):
 
 * ``score_gap``: the widest gap between the best score the program
   reported and the reference's;
@@ -28,8 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-
-import numpy as np
 
 #: Send brackets wider than this do not define a batch's end time.
 _NARROW_S = 1e-4
@@ -159,10 +158,3 @@ def checks(values: dict, limits: dict) -> tuple[bool, dict]:
              for v in out.values())
     return ok, out
 
-
-def filler_tops(texts: list[str], embed, config: dict, seed: int) -> dict:
-    from harness import filler
-
-    q = np.stack([embed(t) for t in texts]).astype(np.float32)
-    scores, ranks = filler.filler_top(q, config, seed)
-    return {t: (scores[j], ranks[j]) for j, t in enumerate(texts)}

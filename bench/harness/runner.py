@@ -1,9 +1,10 @@
 """One run of one cell: set up, drive the window, check, report.
 
-The order matters. Set-up builds, fills and warms the deployment; the
-window drives the traffic; then the device's peak memory is read, the
-trace reduced, the program's state freed, and only then does the
-reference run, so that it never sets the peak.
+The order matters. Set-up builds, fills and warms the configuration's
+deployment; the window drives the traffic; then the trace is reduced, the
+program's state freed (what the deployment's check needs of it is kept on
+the run as ``kept``), the device's peak memory read, and only then does
+the deployment's check run its reference, so that it never sets the peak.
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import sys
 import time
 
 from harness import cell as cellmod
-from harness import check, deploy, roofline, trace, traffic, window
+from harness import roofline, trace, traffic, window
 
 
 class Run:
@@ -33,6 +34,7 @@ class Run:
         self.trace = None         # read trace + reduction (traced run)
         self.peaks = None
         self.device = None
+        self.kept = None          # what the deployment's build kept
 
     @property
     def close(self) -> float:
@@ -89,18 +91,21 @@ def serve_window(spec, seed: int, seconds: float, *, traced: bool,
     from repro.serving import AsyncCacheServer
 
     run = Run(spec, seconds)
-    corpus = deploy.corpus_of(spec.config)
+    deployment = spec.deployment()
+    corpus = deployment.corpus_of(spec.config)
     tracer = None
     if traced:
         from repro.obs import TraceConfig, Tracer
         tracer = Tracer(TraceConfig(sample_rate=1.0, head=0,
                                     max_traces=1 << 22))
-    engine = deploy.build(spec.config, corpus, seed, tracer=tracer)
+    engine, run.kept = deployment.build(spec.config, corpus, seed,
+                                        tracer=tracer)
     if engine_hook is not None:
         engine_hook(engine)
     flow = traffic.generate(spec.traffic, corpus, seed, seconds)
     profile = trace.Profile() if traced else None
-    server = AsyncCacheServer(engine, deploy.scheduler_config(spec.config))
+    server = AsyncCacheServer(engine,
+                              deployment.scheduler_config(spec.config))
 
     async def go():
         await server.start()
@@ -140,49 +145,17 @@ def device_info(count: int) -> dict:
             "count": len(devs), "memory_peak_bytes": peak}
 
 
-def reference_check(run: Run, corpus, seed: int, *, control=None
-                    ) -> tuple[bool, dict, dict]:
-    """Replay the program's batches through the configuration's reference
-    and compare. ``control``, a scorer, puts a lower-precision reference
-    in the program's place instead."""
-    ref = run.spec.reference()
-    config = run.config
-    rec = run.records
-    batches, unplaced = check.replay_batches(rec)
-    embedder = ref.Embedder(int(config["dim"]))
-    texts = sorted({rec.items[i].query for b in batches for i in b})
-    tops = check.filler_tops(texts, embedder.embed, config, seed)
-    entries = [(p.question, p.answer, p.semantic_key) for p in corpus]
-
-    def replay(**kw):
-        cache = ref.ExactCache(config, entries, embedder.embed, tops, **kw)
-        return check.reference_rows(rec, batches, cache)
-
-    owed, orphans = replay()
-    if control is None:
-        served = check.served_rows(rec)
-    else:
-        rows, _ = replay(scorer=control)
-        served = {i: check.Served(r.hit, r.score, r.answer)
-                  for i, r in rows.items()}
-    values, seen = check.compare(served, owed, float(config["threshold"]),
-                                 float(config["limits"]["score_gap"]))
-    values["unanswered"] = sum(r is None for r in rec.resp)
-    values["unplaced"] = len(unplaced) + orphans
-    ok, checks = check.checks(values, config["limits"])
-    seen.update(batches=len(batches), requests=len(rec))
-    return ok, checks, seen
-
-
 def _breakdown(run: Run) -> dict:
     """The ten device ops that took most time, and idle time by what the
-    host was doing in it (engine stage of the batch in service, else no
-    batch in service); each instant is counted once."""
+    host was doing in it: the scheduler's handoff of a batch to the
+    engine (``dispatch``), an engine stage of the batch in service, the
+    loop setting its answers (``resolve``), else no batch in service.
+    Each instant is counted once."""
     red = run.trace["reduced"]
     ops = sorted(red.get("op_s", {}).items(), key=lambda kv: -kv[1])[:10]
     raw, prof = run.trace["raw"], run.trace["profile"]
-    stages = ("embed", "device_step", "near_synthesis", "backend_call",
-              "insert", "respond")
+    stages = ("dispatch", "embed", "device_step", "near_synthesis",
+              "backend_call", "insert", "respond", "resolve")
     host = []
     for tr in run.traces or ():
         # a coalesced waiter's spans lie over the batches it waited
@@ -226,7 +199,7 @@ def run_cell(spec, seed: int, seconds: float, traced: bool, *,
                                t_start=t_start, engine_hook=engine_hook)
     run.device = device_info(spec.chips)
     t_check = time.perf_counter()
-    ok, checks, seen = reference_check(run, corpus, seed)
+    ok, checks, seen = spec.deployment().check(run, corpus, seed)
     log(f"reference check {time.perf_counter() - t_check:.1f}s: {seen}")
     rec = run.records
     metrics = {}

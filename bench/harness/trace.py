@@ -200,3 +200,17 @@ def within(events: list, runs: list) -> dict:
             j += 1
         out[run] = inside
     return out
+
+
+def program_ms(run, name) -> float | None:
+    """Mean busy time in ms of the runs of the programs whose name matches
+    ``name`` (a compiled pattern), over every device traced; None without
+    a trace or such a run."""
+    if run.trace is None:
+        return None
+    per_run = []
+    for dev in run.trace["raw"]["devices"].values():
+        runs = [m for m in dev["modules"] if name.match(m[0])]
+        for evs in within(dev["ops"], runs).values():
+            per_run.append(union_s([(a, b) for _, a, b in evs]))
+    return 1e3 * sum(per_run) / len(per_run) if per_run else None

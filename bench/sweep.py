@@ -37,7 +37,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path.insert(0, str(BENCH))
     sys.path.insert(0, str(ROOT / "src"))
-    from harness import cell, deploy, runner, traffic, window
+    from harness import cell, runner, traffic, window
 
     spec = cell.load(ROOT, args.workload)
     import jax
@@ -50,15 +50,16 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    corpus = deploy.corpus_of(spec.config)
+    deployment = spec.deployment()
+    corpus = deployment.corpus_of(spec.config)
     for rate in (float(r) for r in args.rates.split(",")):
         t0 = time.perf_counter()
-        engine = deploy.build(spec.config, corpus, args.seed)
+        engine, _ = deployment.build(spec.config, corpus, args.seed)
         print(json.dumps({"setup_s": time.perf_counter() - t0}), flush=True)
         flow = traffic.generate(dict(spec.traffic, rate_qps=rate), corpus,
                                 args.seed, args.seconds)
         server = AsyncCacheServer(engine,
-                                  deploy.scheduler_config(spec.config))
+                                  deployment.scheduler_config(spec.config))
 
         async def go():
             await server.start()

@@ -36,9 +36,9 @@ def tiny_spec(name: str) -> cell.Spec:
 
 
 def run_tiny(spec: cell.Spec, *, seconds: float = 2.0, hook=None,
-             control=None, seed: int = SEED):
+             seed: int = SEED):
     """One tiny run; returns (correct, checks, rows seen)."""
     run, corpus = runner.serve_window(spec, seed, seconds, traced=False,
                                       t_start=time.perf_counter(),
                                       engine_hook=hook)
-    return runner.reference_check(run, corpus, seed, control=control)
+    return spec.deployment().check(run, corpus, seed)

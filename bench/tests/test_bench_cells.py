@@ -26,6 +26,33 @@ def test_entry_point_refuses_without_tpu(cell_name, capsys):
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
+def test_paper_deployment_builds_as_before(cell_name):
+    """The configuration's deployment module builds the engine that the
+    harness built before the deployment was a file of its own."""
+    import jax.numpy as jnp
+    from repro.serving import SchedulerConfig, SimulatedLLMBackend
+
+    spec = tiny_spec(cell_name)
+    deployment = spec.deployment()
+    assert deployment is cell.load_module(BENCH / "deployments" / "llm_api.py")
+    corpus = deployment.corpus_of(spec.config)
+    assert len(corpus) == 400
+    engine, kept = deployment.build(spec.config, corpus, SEED)
+    assert kept is None
+    c = engine.cache.config
+    assert (c.dim, c.capacity, c.value_len, c.ttl, c.threshold, c.topk,
+            c.eviction, c.key_dtype) == (384, 8192, 48, 3600.0, 0.8, 4,
+                                         "ring", jnp.float32)
+    assert type(engine.backend) is SimulatedLLMBackend
+    assert (engine.backend.latency_per_call_s, engine.backend.block,
+            engine.backend.cost_per_call_usd) == (0.02, True, 0.002)
+    assert engine.tokenizer.vocab_size == 2 ** 31
+    assert engine.batcher.batch_size == 128
+    assert deployment.scheduler_config(spec.config) == SchedulerConfig(
+        max_batch=128, max_wait_ms=5.0, coalesce=True)
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
 @pytest.mark.parametrize("traced", [False, True])
 def test_cell_runs_end_to_end(cell_name, traced):
     spec = tiny_spec(cell_name)

@@ -1,7 +1,8 @@
 """BENCHMARK.json against the benchmark's contract, and the yardstick's
-arithmetic: every cell resolves its files by name, names and units keep to
-their characters, each per-layer metric's cells report what it moves, and
-the slab scan's work and the peak table are right."""
+arithmetic: every cell resolves its files by name (its configuration's
+deployment among them), names and units keep to their characters, each
+per-layer metric's cells report what it moves, and the slab scan's work
+and the peak table are right."""
 from __future__ import annotations
 
 import json
@@ -16,6 +17,7 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+DEPLOYMENT_EXPORTS = ("corpus_of", "build", "scheduler_config", "check")
 
 
 @pytest.fixture(scope="module")
@@ -65,6 +67,11 @@ def test_cell_resolves_its_files(bench, cell_name):
         assert callable(spec.reader(m, per_layer=True).read)
         assert m["moves"] in e2e
     assert hasattr(spec.reference(), "ExactCache")
+    deployment = spec.deployment()
+    assert deployment.__file__ == str(
+        BENCH / "deployments" / f"{spec.config['deployment']}.py")
+    for name in DEPLOYMENT_EXPORTS:
+        assert callable(getattr(deployment, name)), name
 
 
 def test_per_layer_cells_report_what_they_move(bench):
@@ -80,7 +87,13 @@ def test_every_config_is_used_and_its_file_is_its_own(bench):
     files = [c["file"] for c in bench["configs"]]
     assert len(files) == len(set(files))
     for c in bench["configs"]:
-        assert json.loads((ROOT / c["file"]).read_text())["name"] == c["name"]
+        config = json.loads((ROOT / c["file"]).read_text())
+        assert config["name"] == c["name"]
+        path = BENCH / "deployments" / f"{config['deployment']}.py"
+        assert path.is_file(), path
+        deployment = cell.load_module(path)
+        for name in DEPLOYMENT_EXPORTS:
+            assert callable(getattr(deployment, name)), (path, name)
 
 
 def test_slab_scan_work_of_known_shapes():

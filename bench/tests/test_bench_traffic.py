@@ -8,7 +8,7 @@ import json
 import pytest
 
 from bench_tiny import BENCH
-from harness import deploy, qa, traffic
+from harness import cell, qa, traffic
 
 
 @pytest.fixture(scope="module")
@@ -24,7 +24,9 @@ def test_corpus_is_the_stated_size(corpus):
     config = json.loads(
         (BENCH / "configs" / "paper-8k.json").read_text())
     assert len(corpus) == config["corpus_pairs"] == 5625
-    assert len(deploy.corpus_of(config)) == 5625
+    deployment = cell.load_module(
+        BENCH / "deployments" / f"{config['deployment']}.py")
+    assert len(deployment.corpus_of(config)) == 5625
     assert len({p.question for p in corpus}) == len(corpus)
 
 

@@ -8,15 +8,16 @@ import types
 import numpy as np
 
 from bench_tiny import SEED, tiny_spec
-from harness import deploy, filler, window
+from harness import filler, window
 
 
 def test_fill_program_does_not_depend_on_the_seed():
     """Every seed runs the one compiled fill, so only a checkout's first
     run compiles it."""
     spec = tiny_spec("paper-8k.faq-repeat")
-    corpus = deploy.corpus_of(spec.config)
-    engine = deploy.build(spec.config, corpus, SEED)
+    deployment = spec.deployment()
+    corpus = deployment.corpus_of(spec.config)
+    engine, _ = deployment.build(spec.config, corpus, SEED)
     prog = filler.fill_program(engine.cache, spec.config)
     texts = {prog.lower(engine.runtime, filler.seed_key(s)).as_text()
              for s in (1, 2 ** 33 + 5)}
