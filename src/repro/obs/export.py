@@ -183,6 +183,14 @@ def prometheus_text(metrics, *, cache_stats=None, tenant_stats=None,
                "call.")
     out.sample("repro_hits_released_early_total", None,
                s.hits_released_early)
+    out.family("repro_embed_features_total", "counter",
+               "Feature-token lookups of the embedder on served rows.")
+    out.sample("repro_embed_features_total", None, s.embed_features)
+    out.family("repro_embed_feature_memo_hits_total", "counter",
+               "Feature-token lookups answered by the embedder's memo "
+               "instead of hashing.")
+    out.sample("repro_embed_feature_memo_hits_total", None,
+               s.embed_feature_memo_hits)
     out.family("repro_positive_hits_total", "counter",
                "Judge-confirmed hits by request category.")
     for cat, m in sorted(s.per_category.items()):

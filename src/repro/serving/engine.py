@@ -991,7 +991,17 @@ class CachedEngine:
             # next admission, not only if someone happens to touch them
             self.sessions.expire(self._now)
         t0 = time.perf_counter()
-        emb = jnp.asarray(self.embedder.embed_batch([r.query for r in batch]))
+        emb, lookups, memo_hits = self.embedder.embed_batch_counted(
+            [r.query for r in batch])
+        emb = jnp.asarray(emb)
+        self.metrics.embed_features += sum(lookups[:n_valid])
+        self.metrics.embed_feature_memo_hits += sum(memo_hits[:n_valid])
+        if clock is not None:
+            for tr, n_feat, n_memo in zip(traces[:n_valid], lookups,
+                                          memo_hits):
+                if tr:
+                    tr.annotate(embed_features=n_feat,
+                                embed_memo_hits=n_memo)
         win, wlen, has_ctx = self._session_windows(batch)
         if clock is not None:
             clock.enter("device_step")

@@ -295,6 +295,9 @@ class ServingMetrics:
                                             # duplicates (scheduler, §12.3)
     hits_released_early: int = 0            # hit rows answered at their
                                             # batch's peek (§12.1)
+    embed_features: int = 0                 # feature-token lookups of
+                                            # the embedder (pads excluded)
+    embed_feature_memo_hits: int = 0        # ... answered by its memo
     latency_samples: dict = dataclasses.field(
         default_factory=lambda: defaultdict(LatencyReservoir))
     # path -> LatencyReservoir (bounded, §18.5)

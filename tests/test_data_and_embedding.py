@@ -11,8 +11,11 @@ from repro.data.qa_dataset import (CATEGORIES, build_corpus,
 from repro.data.tokenizer import EOS_ID, HashTokenizer, PAD_ID
 from repro.embedding import (MINILM_L6, HashEmbedder, encode,
                              init_encoder_params)
+from repro.embedding.hash_embedder import MEMO_ENTRIES
 
+import hashlib
 import random
+import re
 
 
 class TestTokenizer:
@@ -92,6 +95,76 @@ class TestHashEmbedder:
 
     def test_dim(self):
         assert HashEmbedder(dim=512).embed("x").shape == (512,)
+
+    @pytest.mark.parametrize("case", ["corpus", "test_queries", "edge_texts",
+                                      "evicted", "width_256_after_384"])
+    def test_memo_is_bit_identical(self, case):
+        """The token memo changes no bit of any vector, whatever the memo
+        holds, and stays within its bound (DESIGN.md §9)."""
+        pairs = build_corpus(2000, seed=0)
+        dims = (384,)
+        if case in ("corpus", "width_256_after_384"):
+            texts = [p.question for p in pairs]
+            assert len(texts) == 5625
+            if case == "width_256_after_384":
+                dims = (384, 256)
+        elif case == "test_queries":
+            texts = [q.query for q in
+                     build_test_queries(pairs, n_per_category=500, seed=1)]
+            assert len(texts) == 2000
+        elif case == "edge_texts":
+            texts = ["", "   ", "?!... --- ;;", "¿Dónde está mi pedido? 配送",
+                     "ß straße STRASSE", "x" * 2000,
+                     " ".join(f"word{i} café" for i in range(200))[:2000]]
+        else:
+            # ~100 distinct tokens a text: more than the memo holds
+            rng = random.Random(0)
+            texts = [" ".join("".join(rng.choice("abcdefghijklmnopqrstuvwxyz")
+                                      for _ in range(7)) for _ in range(6))
+                     for _ in range(1400)]
+        for dim in dims:
+            e = HashEmbedder(dim)
+            got = e.embed_batch(texts)
+            want = np.stack([_unmemoised_embed(t, dim) for t in texts])
+            assert got.dtype == want.dtype and np.array_equal(got, want)
+            assert np.array_equal(e.embed(texts[-1]), want[-1])
+            info = e._slot.cache_info()
+            assert info.currsize <= MEMO_ENTRIES
+            if case == "evicted":
+                assert info.misses > MEMO_ENTRIES + 10_000
+                assert info.currsize == MEMO_ENTRIES
+
+
+def _unmemoised_blake(s: str, salt: int) -> int:
+    return int.from_bytes(
+        hashlib.blake2s(s.encode(), digest_size=8,
+                        salt=salt.to_bytes(8, "little")).digest(), "little")
+
+
+def _unmemoised_embed(text: str, dim: int) -> np.ndarray:
+    """The hash embedder's formula as it stood before the token memo: two
+    digests per feature token, every time."""
+    text = text.lower().strip()
+    words = re.findall(r"\w+", text)
+    feats: dict[int, float] = {}
+
+    def add(tok: str, w: float):
+        idx = _unmemoised_blake(tok, 1) % dim
+        sign = 1.0 if _unmemoised_blake(tok, 2) & 1 else -1.0
+        feats[idx] = feats.get(idx, 0.0) + sign * w
+
+    for w_ in words:
+        add("w:" + w_, 1.0)
+    for a, b in zip(words, words[1:]):
+        add("b:" + a + "_" + b, 1.0 * 0.8)
+    joined = " ".join(words)
+    for n in (3, 4):
+        for i in range(len(joined) - n + 1):
+            add(f"c{n}:" + joined[i:i + n], 0.7 / max(len(joined), 1) * 10)
+    v = np.zeros((dim,), dtype=np.float32)
+    for idx, val in feats.items():
+        v[idx] += val
+    return v / max(np.linalg.norm(v), 1e-12)
 
 
 class TestEncoder:

@@ -860,3 +860,63 @@ class TestEarlyHitRelease:
         assert [r.coalesced for r in responses[2:]] == [False, True, True]
         assert eng.metrics.hits_released_early == 1
 
+
+class TestEmbedMemoCounters:
+    """The embedder's feature-token memo, as the serve path counts it:
+    lookups and memo hits in ``ServingMetrics``, on ``/metrics`` and on
+    each served row's trace."""
+
+    def test_warmed_engine_answers_every_lookup_from_the_memo(self, pairs):
+        eng = make_engine(pairs)
+        eng.warm(pairs)
+        eng.process([Request(query=p.question) for p in pairs[:40]])
+        m = eng.metrics
+        assert m.embed_features > 40
+        assert m.embed_feature_memo_hits == m.embed_features
+
+    def test_unseen_words_miss_the_memo(self, pairs):
+        eng = make_engine(pairs)
+        eng.warm(pairs)
+        eng.process([Request(query=q) for q in DISTINCT_QUERIES])
+        m = eng.metrics
+        assert 0 < m.embed_feature_memo_hits < m.embed_features
+
+    def test_metrics_export_both_counters(self, pairs):
+        from repro.obs.export import MetricsExporter
+        eng = make_engine(pairs)
+        eng.warm(pairs[:20])
+        eng.process([Request(query=q) for q in
+                     [pairs[0].question] + DISTINCT_QUERIES[:2]])
+        rows = dict(ln.rsplit(" ", 1) for ln in
+                    MetricsExporter(eng).render().splitlines()
+                    if ln and not ln.startswith("#"))
+        assert float(rows["repro_embed_features_total"]) == \
+            eng.metrics.embed_features > 0
+        assert float(rows["repro_embed_feature_memo_hits_total"]) == \
+            eng.metrics.embed_feature_memo_hits > 0
+
+    def test_each_served_row_carries_its_counts(self, pairs):
+        from repro.obs import TraceConfig, Tracer
+        eng = make_engine(pairs, tracer=Tracer(
+            TraceConfig(sample_rate=1.0, head=0)))
+        eng.warm(pairs[:20])
+        known = [pairs[i].question for i in range(4)]
+
+        async def serve():
+            sched = SchedulerConfig(max_batch=8, max_wait_ms=2.0)
+            async with AsyncCacheServer(eng, sched) as server:
+                return await asyncio.gather(
+                    *(server.submit(q) for q in known + DISTINCT_QUERIES[:3]))
+
+        asyncio.run(serve())
+        traces = eng.tracer.traces()
+        assert len(traces) == 7
+        for t in traces:
+            n, hits = t.meta["embed_features"], t.meta["embed_memo_hits"]
+            assert 0 <= hits <= n and n > 0
+            if t.meta["path"] == "hit":
+                assert hits == n                # warmed: every token known
+        assert sum(t.meta["embed_features"] for t in traces) == \
+            eng.metrics.embed_features
+        assert sum(t.meta["embed_memo_hits"] for t in traces) == \
+            eng.metrics.embed_feature_memo_hits
